@@ -16,7 +16,7 @@ if __package__ in (None, ""):          # run as a script: python benchmarks/…
     sys.path.insert(0, _ROOT)
     sys.path.insert(0, os.path.join(_ROOT, "src"))   # repro.* for roofline
 
-from benchmarks.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
+from benchmarks.roofline import terms
 
 PAIRS = {
     "starcoder2-3b x train_4k (dp, 16x16)": [
@@ -73,9 +73,7 @@ def _metrics(rec):
              for k in ("flops", "bytes_accessed", "collective_bytes_total")}
     mem = rec["full"]["per_device_memory"]
     m["temp_gib"] = mem["temp_bytes"] / 2**30
-    m["compute_s"] = m["flops"] / PEAK_FLOPS
-    m["memory_s"] = m["bytes_accessed"] / HBM_BW
-    m["collective_s"] = m["collective_bytes_total"] / ICI_BW
+    m.update(terms(m))
     m["bound_s"] = max(m["compute_s"], m["memory_s"], m["collective_s"])
     return m
 

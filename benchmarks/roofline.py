@@ -2,10 +2,9 @@
 roofline terms per (arch x shape x mesh), identify the dominant bottleneck,
 and report MODEL_FLOPS / HLO_FLOPs utilization.
 
-Hardware constants (TPU v5e):
-    peak bf16 compute   197 TFLOP/s per chip
-    HBM bandwidth       819 GB/s per chip
-    ICI link bandwidth  ~50 GB/s per link
+Hardware peaks: `PEAKS`, keyed by `jax.Device.device_kind`; a device kind
+without published peaks is an error, never a default.  The dry-run meshes
+model TPU v5e chips (`DRYRUN_DEVICE_KIND`).
 
 Terms (seconds per training step / per serving call, PER DEVICE):
     compute    = HLO_FLOPs / peak
@@ -25,12 +24,26 @@ import math
 import os
 
 from repro.configs import registry as R
+from repro.errors import ConfigError
 from repro.models import api, param as pm
 from repro.models.param import is_def
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+# Published per-chip peaks.  TPU v5e — Google Cloud documentation, "TPU
+# v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s inter-chip
+# interconnect (taken here as ~50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """{flops, hbm_bw, ici_bw} per second for one chip of `device_kind`."""
+    if device_kind not in PEAKS:
+        raise ConfigError(
+            f"no published peaks for device kind {device_kind!r}: add them "
+            f"to benchmarks/roofline.py PEAKS with their source")
+    return PEAKS[device_kind]
 
 
 def model_params(arch: str) -> tuple[int, int]:
@@ -55,11 +68,12 @@ def model_flops_per_step(arch: str, shape: dict, *, n_devices: int) -> float:
     return 6.0 * n_active * tokens / n_devices
 
 
-def terms(metrics: dict) -> dict:
+def terms(metrics: dict, device_kind: str = DRYRUN_DEVICE_KIND) -> dict:
+    pk = peaks(device_kind)
     return {
-        "compute_s": metrics["flops"] / PEAK_FLOPS,
-        "memory_s": metrics["bytes_accessed"] / HBM_BW,
-        "collective_s": metrics["collective_bytes_total"] / ICI_BW,
+        "compute_s": metrics["flops"] / pk["flops"],
+        "memory_s": metrics["bytes_accessed"] / pk["hbm_bw"],
+        "collective_s": metrics["collective_bytes_total"] / pk["ici_bw"],
     }
 
 
@@ -136,7 +150,8 @@ def run(csv_rows: list | None = None, pattern="experiments/dryrun/*.json"):
     recs = [r for r in recs if r]
     if not recs:
         print("\n== Roofline: no dry-run records found "
-              "(run scripts/run_dryrun_matrix.sh first) ==")
+              "(write them with `python -m repro.launch.dryrun --out "
+              "experiments/dryrun/<arch>__<shape>__single.json` first) ==")
         return
     print("\n== Roofline (per device, per step/call) ==")
     hdr = (f"{'arch':17s} {'shape':12s} {'mesh':8s} {'compute':>9s} "

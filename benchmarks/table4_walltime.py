@@ -77,7 +77,10 @@ def v5e_projection(csv_rows: list | None = None) -> None:
     import glob
     import json
 
-    from benchmarks.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
+    from benchmarks.roofline import DRYRUN_DEVICE_KIND, peaks
+
+    pk = peaks(DRYRUN_DEVICE_KIND)
+    PEAK_FLOPS, HBM_BW, ICI_BW = pk["flops"], pk["hbm_bw"], pk["ici_bw"]
 
     print("\n== Table 4 (v5e projection from dry-run rooflines) ==")
     print(f"{'arch':18s} {'parallel s/step':>15s} {'QSR(H=4) s/step':>15s} "

@@ -297,7 +297,8 @@ def _lane_viewer(batch_arg: bool, lanes):
 
 
 def make_bucketed_round(cfg, run_cfg, synth: Callable | None = None,
-                        spec=None, *, batch_arg: bool = False):
+                        spec=None, *, batch_arg: bool = False,
+                        local_workers: bool = False):
     """Padded, masked communication round.
 
     Host data:   fn(state, batches [Hp, W, B, ...], lrs [Hp], mask [Hp])
@@ -317,7 +318,7 @@ def make_bucketed_round(cfg, run_cfg, synth: Callable | None = None,
     recompiling.
     """
     local_step = LU.make_local_step(cfg, run_cfg, with_metrics=True,
-                                    spec=spec)
+                                    spec=spec, local_workers=local_workers)
     sync = make_sync(run_cfg, spec=spec)
     body = _masked_body(local_step)
 
@@ -351,7 +352,8 @@ def make_bucketed_round(cfg, run_cfg, synth: Callable | None = None,
 
 
 def make_partial_round(cfg, run_cfg, synth: Callable | None = None,
-                       spec=None, *, batch_arg: bool = False):
+                       spec=None, *, batch_arg: bool = False,
+                       local_workers: bool = False):
     """Bucketed round whose boundary sync averages over ARRIVED workers.
 
     Host data:   fn(state, membership [W], batches [Hp,...], lrs, mask)
@@ -371,7 +373,7 @@ def make_partial_round(cfg, run_cfg, synth: Callable | None = None,
     collective at all.
     """
     local_step = LU.make_local_step(cfg, run_cfg, with_metrics=True,
-                                    spec=spec)
+                                    spec=spec, local_workers=local_workers)
     sync = make_sync_partial(run_cfg, spec=spec)
     body = _masked_body(local_step)
 
@@ -404,7 +406,8 @@ def make_partial_round(cfg, run_cfg, synth: Callable | None = None,
     return round_fn
 
 
-def make_exact_round(cfg, run_cfg, synth: Callable | None = None, spec=None):
+def make_exact_round(cfg, run_cfg, synth: Callable | None = None, spec=None,
+                     *, local_workers: bool = False):
     """Legacy exact-H round (one compile per distinct H) + engine telemetry.
 
     Same state arithmetic as `local_update.make_train_round`; kept as the
@@ -412,7 +415,7 @@ def make_exact_round(cfg, run_cfg, synth: Callable | None = None, spec=None):
     tested bitwise against.
     """
     local_step = LU.make_local_step(cfg, run_cfg, with_metrics=True,
-                                    spec=spec)
+                                    spec=spec, local_workers=local_workers)
     sync = make_sync(run_cfg, spec=spec)
 
     def finish_exact(state, losses, gns):
@@ -444,7 +447,8 @@ def make_exact_round(cfg, run_cfg, synth: Callable | None = None, spec=None):
 
 def make_overlap_round(cfg, run_cfg, synth: Callable | None = None,
                        spec=None, *, depth: int = 0,
-                       apply_pending: bool = True, batch_arg: bool = False):
+                       apply_pending: bool = True, batch_arg: bool = False,
+                       local_workers: bool = False):
     """Bucketed round with the sync split across the round boundary.
 
     Host data:   fn(state, pending?, batches [Hp, ...], lrs [Hp], mask [Hp])
@@ -459,7 +463,7 @@ def make_overlap_round(cfg, run_cfg, synth: Callable | None = None,
     round's sync — new_pending, handed to the next program.
     """
     local_step = LU.make_local_step(cfg, run_cfg, with_metrics=True,
-                                    spec=spec)
+                                    spec=spec, local_workers=local_workers)
     begin = make_sync_begin(run_cfg, spec=spec)
     apply_ = make_sync_apply(run_cfg, spec=spec)
     body = _masked_body(local_step)
@@ -667,8 +671,11 @@ class RoundEngine:
             params_single = pm.init_params(mod.param_defs(self.cfg),
                                            jax.random.PRNGKey(self.seed),
                                            jnp.float32)
-        state = LU.init_state(self.cfg, self.run_cfg, params_single,
-                              self.workers)
+        # on a mesh, one worker's state: _to_global broadcasts it over the
+        # worker rows shard by shard, so the W-stacked state is never built
+        # whole on one device
+        w = 1 if self.mesh is not None else self.workers
+        state = LU.init_state(self.cfg, self.run_cfg, params_single, w)
         if self.layout != "tree":
             state = flat.to_flat_state(self._ensure_spec(params_single), state)
         if self.mesh is not None:
@@ -678,7 +685,22 @@ class RoundEngine:
     def _to_global(self, state: Pytree) -> Pytree:
         """Lay the flat state out onto the engine's mesh as global arrays
         (flat.make_global: works single-process and across real
-        `jax.distributed` processes alike)."""
+        `jax.distributed` processes alike).  Worker-stacked [W, N] leaves
+        (two-entry specs) may come with a single row, which every worker
+        then starts from."""
+        leaves, td = jax.tree.flatten(state)
+        out = []
+        for x, sh in zip(leaves, self._state_shardings(td)):
+            shape = np.shape(x)
+            if len(sh.spec) == 2:
+                shape = (self.workers,) + shape[1:]
+            out.append(flat.make_global(x, self.mesh, sh.spec, shape=shape))
+        return jax.tree.unflatten(td, out)
+
+    def _state_shardings(self, treedef) -> list:
+        """Per-leaf NamedShardings of a flat state (structure `treedef`) on
+        the engine's mesh: worker rows over the worker axes, the flat dim
+        over the shard axes."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         sspec = flat.flat_state_specs(self.run_cfg, self.spec.worker_axes,
@@ -687,10 +709,20 @@ class RoundEngine:
         # NamedSharding so flatten_up_to treats each spec as one leaf
         ns = jax.tree.map(lambda s: NamedSharding(self.mesh, s), sspec,
                           is_leaf=lambda x: isinstance(x, P))
-        leaves, td = jax.tree.flatten(state)
-        shardings = td.flatten_up_to(ns)
-        return jax.tree.unflatten(td, [flat.make_global(x, self.mesh, sh.spec)
-                                       for x, sh in zip(leaves, shardings)])
+        return treedef.flatten_up_to(ns)
+
+    def _keep_state_layout(self, fn):
+        """Pin a mesh program's output state to the state's own shardings.
+        Left to propagation, the consensus the sync all-gathers comes out
+        replicated, and every device then holds every worker's params."""
+        def pinned(state, *args):
+            out = fn(state, *args)
+            leaves, td = jax.tree.flatten(out[0])
+            new = jax.tree.unflatten(td, [
+                jax.lax.with_sharding_constraint(x, sh)
+                for x, sh in zip(leaves, self._state_shardings(td))])
+            return (new,) + tuple(out[1:])
+        return pinned
 
     def params_single(self, state: Pytree) -> Pytree:
         """Worker-0 params as the model pytree, whatever the layout — the
@@ -722,23 +754,31 @@ class RoundEngine:
             self.cache_hits += 1
             return self._programs[key]
         spec = self._ensure_spec() if self.layout != "tree" else None
+        # without a mesh every worker replica lives on one device
+        local = self.mesh is None
         if self.sync_mode == "overlap":
             fn = make_overlap_round(self.cfg, self.run_cfg, self._synth,
                                     spec, depth=self.overlap_depth,
                                     apply_pending=apply_pending,
-                                    batch_arg=self.adaptive_batch)
+                                    batch_arg=self.adaptive_batch,
+                                    local_workers=local)
             donate = (0, 1) if apply_pending else (0,)
         elif self.sync_mode == "partial":
             fn = make_partial_round(self.cfg, self.run_cfg, self._synth,
-                                    spec, batch_arg=self.adaptive_batch)
+                                    spec, batch_arg=self.adaptive_batch,
+                                    local_workers=local)
             donate = (0,)
         elif self.mode == "bucketed":
             fn = make_bucketed_round(self.cfg, self.run_cfg, self._synth,
-                                     spec, batch_arg=self.adaptive_batch)
+                                     spec, batch_arg=self.adaptive_batch,
+                                     local_workers=local)
             donate = (0,)
         else:
-            fn = make_exact_round(self.cfg, self.run_cfg, self._synth, spec)
+            fn = make_exact_round(self.cfg, self.run_cfg, self._synth, spec,
+                                  local_workers=local)
             donate = (0,)
+        if self.mesh is not None:
+            fn = self._keep_state_layout(fn)
         jit_kw = {"donate_argnums": donate} if self.donate else {}
         self._programs[key] = jax.jit(fn, **jit_kw)
         self.compiles += 1
@@ -756,6 +796,24 @@ class RoundEngine:
         Returns (state, metrics) where metrics holds device scalars
         {"loss", "grad_norm", "divergence"} computed in-graph.
         """
+        fn, args = self._round_call(t, h, lr_fn)
+        if self.sync_mode == "overlap":
+            state, self._pending, metrics = fn(state, *args)
+        else:
+            state, metrics = fn(state, *args)
+        self.h_trace.append((t, h))
+        return state, metrics
+
+    def compiled_round(self, state: Pytree, t: int, h: int, lr_fn):
+        """The compiled program `run_round(state, t, h, lr_fn)` runs, for
+        inspection (`memory_analysis()`, `as_text()`).  A program already
+        compiled comes from JAX's compilation cache where one is on."""
+        fn, args = self._round_call(t, h, lr_fn)
+        return fn.lower(state, *args).compile()
+
+    def _round_call(self, t: int, h: int, lr_fn):
+        """(jitted program, its arguments after the state) for the round
+        starting at step t with period h."""
         hp = bucket_pow2(h) if self.mode == "bucketed" else h
         # the schedule is only defined on [0, total_steps): query it for the
         # h valid steps and fill the hp - h padded lanes with the last valid
@@ -784,14 +842,9 @@ class RoundEngine:
             args.append(jnp.int32(self.batch_lanes))
         if self.sync_mode == "partial":
             args.insert(0, jnp.asarray(self.membership, jnp.float32))
-        if self.sync_mode == "overlap":
-            if self._pending is not None:
-                args.insert(0, self._pending)
-            state, self._pending, metrics = fn(state, *args)
-        else:
-            state, metrics = fn(state, *args)
-        self.h_trace.append((t, h))
-        return state, metrics
+        if self.sync_mode == "overlap" and self._pending is not None:
+            args.insert(0, self._pending)
+        return fn, args
 
     def synced_view(self, state: Pytree) -> Pytree:
         """State with the in-flight sync applied, WITHOUT consuming it —

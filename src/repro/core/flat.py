@@ -160,6 +160,26 @@ class FlatParamSpace:
                 leaves[i] = jnp.reshape(sl, buf.shape[:lead] + lf.shape)
         return jax.tree.unflatten(self.treedef, leaves)
 
+    def worker_views(self, bufs: dict[str, jax.Array]) -> Pytree:
+        """`{bucket: [W, N]}` -> pytree of `[W, *shape]` leaves, sliced one
+        worker at a time: the values of `unflatten(bufs, lead=1)`, but the
+        TPU compiler takes minutes over a batched slice + reshape of a
+        model-sized bucket and about a second over W unbatched ones.  Only
+        for workers on one device: with W sharded over a mesh, a per-worker
+        slice would move data between devices."""
+        w = next(iter(bufs.values())).shape[0]
+        per = [self.unflatten({b: x[i] for b, x in bufs.items()})
+               for i in range(w)]
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *per)
+
+    def worker_buffers(self, tree: Pytree) -> dict[str, jax.Array]:
+        """Inverse of `worker_views`: `[W, *shape]` leaves -> `{bucket:
+        [W, N]}`, flattened one worker at a time."""
+        w = jax.tree.leaves(tree)[0].shape[0]
+        per = [self.flatten(jax.tree.map(lambda x: x[i], tree))
+               for i in range(w)]
+        return {b: jnp.stack([p[b] for p in per]) for b in per[0]}
+
     # -- per-tensor reductions over the flat buffer ------------------------
 
     def segment_max(self, bucket: str, x: jax.Array) -> jax.Array:
@@ -272,16 +292,19 @@ def flat_state_specs(run_cfg, waxes, spec):
     return out
 
 
-def make_global(x, mesh, pspec):
+def make_global(x, mesh, pspec, shape=None):
     """One host-replicated value -> a global array laid out on `mesh`.
     `make_array_from_callback` builds the buffer from its addressable shards
     only, so the same call works single-process (simulated devices) and
     across real `jax.distributed` processes — every process holds the
-    identical host value, each contributes its own shards.  Shared by
-    RoundEngine init and the multihost harness so the two stay bitwise
-    comparable."""
+    identical host value, each contributes its own shards.  With `shape`,
+    the value is broadcast to it first (a view: each shard is cut from the
+    broadcast, which is never materialized).  Shared by RoundEngine init
+    and the multihost harness so the two stay bitwise comparable."""
     from jax.sharding import NamedSharding
     xnp = np.asarray(x)
+    if shape is not None:
+        xnp = np.broadcast_to(xnp, shape)
     return jax.make_array_from_callback(xnp.shape, NamedSharding(mesh, pspec),
                                         lambda idx: xnp[idx])
 

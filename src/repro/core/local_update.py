@@ -71,7 +71,8 @@ def make_loss(cfg, run_cfg):
     return partial(mod.loss_fn, cfg, remat=remat, **kw)
 
 
-def make_local_step(cfg, run_cfg, *, with_metrics: bool = False, spec=None):
+def make_local_step(cfg, run_cfg, *, with_metrics: bool = False, spec=None,
+                    local_workers: bool = False):
     """One per-worker optimizer step: NO cross-worker communication.
 
     state leaves have leading worker axis W; batch leaves have leading W.
@@ -85,27 +86,33 @@ def make_local_step(cfg, run_cfg, *, with_metrics: bool = False, spec=None):
     directly — the transpose of a slice is a disjoint scatter, so each
     element's gradient is bitwise the per-leaf gradient — and the optimizer
     runs one fused update per bucket instead of one per leaf.
+    `local_workers=True` says every worker replica lives on one device: the
+    views are then sliced and the per-leaf gradients concatenated worker by
+    worker (`spec.worker_views` / `worker_buffers`), the same values through
+    ops the TPU compiler handles in seconds rather than minutes at
+    published widths.
     """
     tree_loss_fn = make_loss(cfg, run_cfg)
+    tree_grad_fn = jax.value_and_grad(tree_loss_fn)
     if spec is None:
-        loss_fn = tree_loss_fn
+        grad_fn = tree_grad_fn
     else:
-        def loss_fn(bufs, batch):
-            return tree_loss_fn(spec.unflatten(bufs), batch)
+        grad_fn = jax.value_and_grad(
+            lambda bufs, batch: tree_loss_fn(spec.unflatten(bufs), batch))
     opt = make_optimizer(run_cfg)
 
     mb = getattr(run_cfg, "microbatch", 1)
 
-    def _value_and_grad(params, batch):
+    def _value_and_grad(grad_fn, params, batch):
         """Per-worker loss/grad, optionally microbatched (grad accumulation
         over `mb` sequential chunks — peak activation memory / mb)."""
         if mb <= 1:
-            return jax.value_and_grad(loss_fn)(params, batch)
+            return grad_fn(params, batch)
         chunks = jax.tree.map(
             lambda x: x.reshape((mb, x.shape[0] // mb) + x.shape[1:]), batch)
 
         def body(acc, chunk):
-            loss, g = jax.value_and_grad(loss_fn)(params, chunk)
+            loss, g = grad_fn(params, chunk)
             acc_loss, acc_g = acc
             return (acc_loss + loss / mb,
                     jax.tree.map(lambda a, b: a + b / mb, acc_g, g)), None
@@ -123,12 +130,17 @@ def make_local_step(cfg, run_cfg, *, with_metrics: bool = False, spec=None):
             # single replica (fsdp pod-worker): skip vmap so explicit
             # shard_map regions (MoE dispatch) can run inside the loss
             loss, g = _value_and_grad(
-                jax.tree.map(lambda x: x[0], state["params"]),
+                grad_fn, jax.tree.map(lambda x: x[0], state["params"]),
                 jax.tree.map(lambda x: x[0], batch))
             losses = loss[None]
             grads = jax.tree.map(lambda x: x[None], g)
+        elif spec is not None and local_workers:
+            losses, tree_grads = jax.vmap(partial(_value_and_grad,
+                                                  tree_grad_fn))(
+                spec.worker_views(state["params"]), batch)
+            grads = spec.worker_buffers(tree_grads)
         else:
-            losses, grads = jax.vmap(_value_and_grad)(
+            losses, grads = jax.vmap(partial(_value_and_grad, grad_fn))(
                 state["params"], batch)
         # optimizer update is elementwise -> applies across the W axis as-is
         params, opt_state = opt.update(state["params"], state["opt"], grads, lr)

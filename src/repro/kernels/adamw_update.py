@@ -1,16 +1,21 @@
 """Fused AdamW update — Pallas TPU kernel.
 
 The innermost loop of every local step in Local AdamW (paper Alg. 2 line 12):
-p, m, v are streamed through VMEM in 1D blocks; all five elementwise ops
-(two moment updates, bias correction, weight decay, parameter step) fuse
+p, m, v are streamed through VMEM in lane-dense blocks; all five elementwise
+ops (two moment updates, bias correction, weight decay, parameter step) fuse
 into one pass, so HBM traffic is the roofline minimum (read p,m,v,g; write
 p,m,v) instead of one round-trip per op.
 
-Inputs may be any rank (the kernel flattens): under the tree layout the
-optimizer invokes this once per pytree leaf, paying up to one _BLOCK of
-padding and one kernel launch *per tensor*; under the flat layout
-(core/flat.py) it is invoked once per dtype bucket on the [W, N] buffer —
-one launch and at most one block of padding for the whole model.
+Inputs may be any rank: the kernel views them as [rows, n] with rows the
+leading dim (the worker axis W of a flat [W, N] bucket) and blocks the
+n dim in multiples of 128 lanes.  The grid covers a ragged last block
+without padding (Pallas masks its out-of-bounds writes), so no copy of the
+model-sized buffers is made, and p, m, v are updated in place.  Under the
+tree layout the optimizer invokes this once per pytree leaf; under the flat
+layout (core/flat.py) once per dtype bucket.
+
+The scalars (lr and the two bias corrections 1 - beta**step) are computed
+in the wrapper and ride in SMEM.
 """
 from __future__ import annotations
 
@@ -19,24 +24,29 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-_BLOCK = 64 * 1024  # 64K elements * (4B fp32 * ~7 tensors) ~ 1.8 MiB VMEM
+_BLOCK = 64 * 1024  # elements per operand block (8 sublanes x 8192 lanes)
 
 
-def _adamw_kernel(p_ref, m_ref, v_ref, g_ref, sc_ref, po_ref, mo_ref, vo_ref,
+def _adamw_kernel(sc_ref, p_ref, m_ref, v_ref, g_ref, po_ref, mo_ref, vo_ref,
                   *, beta1, beta2, eps, weight_decay):
-    lr = sc_ref[0]
-    step = sc_ref[1]
+    lr, bc1, bc2 = sc_ref[0], sc_ref[1], sc_ref[2]
     g = g_ref[...].astype(jnp.float32)
     m = beta1 * m_ref[...] + (1.0 - beta1) * g
     v = beta2 * v_ref[...] + (1.0 - beta2) * g * g
-    bc1 = 1.0 - beta1 ** step
-    bc2 = 1.0 - beta2 ** step
     upd = (m / bc1) / (jnp.sqrt(v / bc2) + eps)
     pf = p_ref[...].astype(jnp.float32)
     po_ref[...] = (pf - lr * (upd + weight_decay * pf)).astype(po_ref.dtype)
     mo_ref[...] = m
     vo_ref[...] = v
+
+
+def lane_block(rows: int, n: int, budget: int = _BLOCK) -> int:
+    """Lanes per block of a [rows, n] elementwise kernel: a multiple of 128
+    with rows (padded to 8 sublanes) x lanes <= budget, or all of n."""
+    lanes = max(128, budget // max(rows, 8) // 128 * 128)
+    return n if n <= lanes else lanes
 
 
 @partial(jax.jit,
@@ -45,26 +55,28 @@ def adamw_update(p, m, v, g, *, lr, beta1, beta2, eps, weight_decay, step,
                  interpret: bool = False):
     """All tensors same shape; m, v fp32. Returns (new_p, new_m, new_v)."""
     shape = p.shape
-    n = p.size
-    blk = min(_BLOCK, n)
-    pad = (-n) % blk
-    flat = lambda x: jnp.pad(x.reshape(-1), (0, pad))
-    pf, mf, vf, gf = flat(p), flat(m), flat(v), flat(g)
+    rows = shape[0] if p.ndim >= 2 else 1
+    n = p.size // rows
+    as2d = lambda x: x.reshape(rows, n)
+    step = jnp.asarray(step, jnp.float32)
     scalars = jnp.stack([jnp.asarray(lr, jnp.float32),
-                         jnp.asarray(step, jnp.float32)])
-    grid = ((n + pad) // blk,)
-    spec = pl.BlockSpec((blk,), lambda i: (i,))
+                         1.0 - beta1 ** step, 1.0 - beta2 ** step])
+    blk = lane_block(rows, n)
+    spec = pl.BlockSpec((rows, blk), lambda i: (0, i))
     po, mo, vo = pl.pallas_call(
         partial(_adamw_kernel, beta1=beta1, beta2=beta2, eps=eps,
                 weight_decay=weight_decay),
-        grid=grid,
-        in_specs=[spec, spec, spec, spec,
-                  pl.BlockSpec((2,), lambda i: (0,))],
+        grid=(pl.cdiv(n, blk),),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  spec, spec, spec, spec],
         out_specs=[spec, spec, spec],
-        out_shape=[jax.ShapeDtypeStruct(pf.shape, p.dtype),
-                   jax.ShapeDtypeStruct(mf.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(vf.shape, jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((rows, n), p.dtype),
+                   jax.ShapeDtypeStruct((rows, n), jnp.float32),
+                   jax.ShapeDtypeStruct((rows, n), jnp.float32)],
+        input_output_aliases={1: 0, 2: 1, 3: 2},
+        name="adamw_update",
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(pf, mf, vf, gf, scalars)
-    unflat = lambda x: x[:n].reshape(shape)
-    return unflat(po), unflat(mo), unflat(vo)
+    )(scalars, as2d(p), as2d(m), as2d(v), as2d(g))
+    return po.reshape(shape), mo.reshape(shape), vo.reshape(shape)

@@ -55,16 +55,15 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                                prefix_len=prefix_len, q_offset=q_offset,
                                scale=scale, k_positions=k_positions,
                                interpret=(_BACKEND == "interpret"))
-    traced_window = isinstance(window, jax.core.Tracer)
-    if _BACKEND == "jnp" or k_positions is not None or ragged or traced_window:
-        # full-sequence ring/ragged shapes — and traced windows from the
-        # scan-stacked prefill — stay on the jnp path: the block kernel's
-        # masks are static.
+    if _BACKEND == "jnp" or k_positions is not None or ragged:
+        # full-sequence ring-buffer / ragged-offset shapes stay on the jnp
+        # path: the block kernel takes one scalar query offset and dense
+        # key positions.
         return ref.attention(q, k, v, causal=causal, window=window,
                              prefix_len=prefix_len, q_offset=q_offset,
                              scale=scale, k_positions=k_positions)
     from repro.kernels import flash_attention as _k
-    return _k.flash_attention(q, k, v, causal=causal, window=int(window),
+    return _k.flash_attention(q, k, v, causal=causal, window=window,
                               prefix_len=prefix_len, q_offset=q_offset,
                               scale=scale, interpret=(_BACKEND == "interpret"))
 

@@ -8,7 +8,9 @@ broadcast of the new consensus back to every replica — all in ONE pass
 through VMEM.  The tree-layout path runs the same math as ~6 separate jnp
 ops, each round-tripping the (model-sized) delta through HBM; here HBM
 traffic is the roofline minimum: read p, anchor (+ scale, mu), write p,
-anchor (+ mu).
+anchor (+ mu).  The flat-bucket kernels' grids cover a ragged last block
+without padding (Pallas masks its out-of-bounds writes), and they update
+p, anchor and mu in place, so no copy of a model-sized buffer is made.
 
 The worker-mean all-reduce itself is GSPMD's (the W axis is sharded over
 the worker mesh axes); inside the kernel the W axis is the block's leading
@@ -61,35 +63,34 @@ def sync_flat_update(p, anchor, *, scale=None, mu=None, momentum: float = 0.0,
     w, n = p.shape
     quantize = scale is not None
     blk = min(n, max(8 * 128, _BLOCK // max(w, 1)))
-    pad = (-n) % blk
-    pad1 = lambda x, v=0.0: jnp.pad(x, (0, pad), constant_values=v)
-    pp = jnp.pad(p, ((0, 0), (0, pad)))
-    args = [pp, pad1(anchor)]
+    args = [p, anchor]
     spec2 = pl.BlockSpec((w, blk), lambda i: (0, i))
     spec1 = pl.BlockSpec((blk,), lambda i: (i,))
     in_specs = [spec2, spec1]
     if quantize:
-        args.append(pad1(scale, 1.0))   # pad scale 1: guards the pad's 0/0
+        args.append(scale)
         in_specs.append(spec1)
     if momentum > 0.0:
-        args.append(pad1(mu))
+        args.append(mu)
         in_specs.append(spec1)
-    out_shape = [jax.ShapeDtypeStruct(pp.shape, p.dtype),
-                 jax.ShapeDtypeStruct((n + pad,), anchor.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((w, n), p.dtype),
+                 jax.ShapeDtypeStruct((n,), anchor.dtype)]
     out_specs = [spec2, spec1]
+    aliases = {0: 0, 1: 1}
     if momentum > 0.0:
-        out_shape.append(jax.ShapeDtypeStruct((n + pad,), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((n,), jnp.float32))
         out_specs.append(spec1)
+        aliases[len(args) - 1] = 2
 
     def body(*refs):
         _kernel(refs, momentum=momentum, quantize=quantize, n_in=len(args))
 
-    out = pl.pallas_call(body, grid=((n + pad) // blk,), in_specs=in_specs,
+    out = pl.pallas_call(body, grid=(pl.cdiv(n, blk),), in_specs=in_specs,
                          out_specs=out_specs, out_shape=out_shape,
+                         input_output_aliases=aliases,
+                         name="sync_flat_update",
                          interpret=interpret)(*args)
-    new_p, new_a = out[0][:, :n], out[1][:n]
-    new_mu = out[2][:n] if momentum > 0.0 else None
-    return new_p, new_a, new_mu
+    return out[0], out[1], (out[2] if momentum > 0.0 else None)
 
 
 # --------------------------------------------------------------------------
@@ -123,33 +124,33 @@ def sync_apply_update(step_in, anchor, *, scale=None, mu=None,
     (n,) = step_in.shape
     quantize = scale is not None
     blk = min(n, _BLOCK)
-    pad = (-n) % blk
-    pad1 = lambda x, v=0.0: jnp.pad(x, (0, pad), constant_values=v)
-    args = [pad1(step_in), pad1(anchor)]
+    args = [step_in, anchor]
     spec1 = pl.BlockSpec((blk,), lambda i: (i,))
     in_specs = [spec1, spec1]
     if quantize:
-        args.append(pad1(scale, 1.0))
+        args.append(scale)
         in_specs.append(spec1)
     if momentum > 0.0:
-        args.append(pad1(mu))
+        args.append(mu)
         in_specs.append(spec1)
-    out_shape = [jax.ShapeDtypeStruct((n + pad,), anchor.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((n,), anchor.dtype)]
     out_specs = [spec1]
+    aliases = {1: 0}
     if momentum > 0.0:
-        out_shape.append(jax.ShapeDtypeStruct((n + pad,), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((n,), jnp.float32))
         out_specs.append(spec1)
+        aliases[len(args) - 1] = 1
 
     def body(*refs):
         _apply_kernel(refs, momentum=momentum, quantize=quantize,
                       n_in=len(args))
 
-    out = pl.pallas_call(body, grid=((n + pad) // blk,), in_specs=in_specs,
+    out = pl.pallas_call(body, grid=(pl.cdiv(n, blk),), in_specs=in_specs,
                          out_specs=out_specs, out_shape=out_shape,
+                         input_output_aliases=aliases,
+                         name="sync_apply_update",
                          interpret=interpret)(*args)
-    new_a = out[0][:n]
-    new_mu = out[1][:n] if momentum > 0.0 else None
-    return new_a, new_mu
+    return out[0], (out[1] if momentum > 0.0 else None)
 
 
 # --------------------------------------------------------------------------

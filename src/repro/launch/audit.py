@@ -1,4 +1,5 @@
 import os
+os.environ.setdefault("JAX_PLATFORMS", "cpu")  # simulated host devices
 os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_EXTRA_XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=" +
                            os.environ.get("REPRO_DRYRUN_DEVICES", "8")).strip()

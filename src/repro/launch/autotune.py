@@ -1,4 +1,5 @@
 import os
+os.environ.setdefault("JAX_PLATFORMS", "cpu")  # simulated host devices
 os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_EXTRA_XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=" +
                            os.environ.get("REPRO_DRYRUN_DEVICES", "8")).strip()
@@ -20,9 +21,10 @@ For one (mesh, policy) this module enumerates candidate sync *plans* —
     unquantized host mean on identical worker noise: max |param diff| at the
     end.  Measured, never assumed (the ring's per-hop requantization bound
     `ring_tolerance` disqualifies a plan that exceeds it).  Runs in a
-    watchdog subprocess (`measure_drift_guarded`): XLA's in-process CPU
-    collective rendezvous can rarely deadlock on an oversubscribed host, so
-    a hung measurement is killed and retried instead of hanging the tuner.
+    watchdog subprocess (`measure_drift_guarded`) on the CPU backend: XLA's
+    in-process CPU collective rendezvous can rarely deadlock on an
+    oversubscribed host, so a hung measurement is killed and retried
+    instead of hanging the tuner.  On an accelerator it runs in-process.
   * s_per_round — full RoundEngine rounds (local steps + sync) timed on the
     mesh, the wall-clock axis that catches a plan whose byte win costs too
     many kernel launches.
@@ -195,6 +197,20 @@ def measure_drift(cfg, run_cfg, mesh, policy: str, *, rounds: int = 3,
             "rounds": rounds}
 
 
+def _measure_drift_named(wname: str, *, arch: str, mesh: str, policy: str,
+                         smoke: bool, rounds: int) -> dict:
+    """measure_drift for the wire named `wname` on the debug mesh `mesh`."""
+    from repro.configs import registry as R
+    _, quantize, swire = next(x for x in WIRES if x[0] == wname)
+    cfg = R.get_smoke_config(arch) if smoke else R.get_config(arch)
+    pods, n_data, n_model = _mesh_tuple(mesh)
+    jmesh = make_debug_mesh(n_data, n_model, pods=pods)
+    run_cfg = RunConfig(sharding=policy, sync_quantize=quantize,
+                        sync_wire=swire, schedule="constant", h_base=4,
+                        total_steps=10 ** 6, remat=False)
+    return measure_drift(cfg, run_cfg, jmesh, policy, rounds=rounds)
+
+
 def measure_drift_guarded(wname: str, *, arch: str, mesh: str, policy: str,
                           smoke: bool = True, rounds: int = 3,
                           timeout: float = 300.0, attempts: int = 3) -> dict:
@@ -206,7 +222,14 @@ def measure_drift_guarded(wname: str, *, arch: str, mesh: str, policy: str,
     scheduled while every other rank waits forever at the rendezvous.  The
     race cannot be closed from client code, so the guard is containment:
     run the measurement in a fresh process, kill it past `timeout`, retry.
-    A healthy measurement takes well under a minute at smoke scale."""
+    A healthy measurement takes well under a minute at smoke scale.
+
+    The race is the CPU backend's: on an accelerator the measurement runs
+    in this process, which holds the device a child could not open."""
+    if jax.default_backend() != "cpu":
+        return _measure_drift_named(wname, arch=arch, mesh=mesh,
+                                    policy=policy, smoke=smoke,
+                                    rounds=rounds)
     import subprocess
     src = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -410,18 +433,10 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.drift_worker:
-        from repro.configs import registry as R
-        _, quantize, swire = next(x for x in WIRES
-                                  if x[0] == args.drift_worker)
-        cfg = (R.get_config(args.arch) if args.full
-               else R.get_smoke_config(args.arch))
-        pods, n_data, n_model = _mesh_tuple(args.mesh)
-        jmesh = make_debug_mesh(n_data, n_model, pods=pods)
-        run_cfg = RunConfig(sharding=args.policy, sync_quantize=quantize,
-                            sync_wire=swire, schedule="constant", h_base=4,
-                            total_steps=10 ** 6, remat=False)
-        print(json.dumps(measure_drift(cfg, run_cfg, jmesh, args.policy,
-                                       rounds=args.drift_rounds)))
+        print(json.dumps(_measure_drift_named(
+            args.drift_worker, arch=args.arch, mesh=args.mesh,
+            policy=args.policy, smoke=not args.full,
+            rounds=args.drift_rounds)))
         return
 
     rec = autotune(args.arch, mesh=args.mesh, policy=args.policy,

@@ -317,6 +317,16 @@ def host_callbacks(hlo_text: str) -> list[str]:
     return [ln.strip() for ln in hlo_text.splitlines() if _HOST_CALL.search(ln)]
 
 
+_PALLAS_CALL = re.compile(r'%([A-Za-z_]+)[\w.]*\s*=[^\n]*'
+                          r'custom_call_target="tpu_custom_call"')
+
+
+def pallas_kernels(hlo_text: str) -> list[str]:
+    """Names of the Pallas TPU kernels (`tpu_custom_call`s) in a compiled
+    program, one entry per call; each kernel's pallas_call names it."""
+    return _PALLAS_CALL.findall(hlo_text)
+
+
 def summarize(compiled, *, n_devices: int) -> dict:
     cost = compiled.cost_analysis()
     if isinstance(cost, list):

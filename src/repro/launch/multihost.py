@@ -62,7 +62,7 @@ Spawn it yourself (the multihost CPU runbook, README §Multihost):
   PYTHONPATH=src python -m repro.launch.multihost \
       --spawn 2 --total-devices 8 --mesh 2x2x2 --policy fsdp --quantize
 
-Worker environment (set by --spawn, scripts/launch_v5e_pod.sh, or you):
+Worker environment (set by --spawn, or by you):
   REPRO_COORDINATOR   host:port of process 0
   REPRO_NUM_PROCESSES total process count
   REPRO_PROCESS_ID    this process's index
@@ -107,10 +107,7 @@ def initialize(*, retries: int = 3, backoff: float = 0.5) -> bool:
     if not coord:
         return False
     import jax
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass  # option absent/renamed in this jax: rely on its default
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     last = None
     for attempt in range(max(1, retries)):
         try:
@@ -230,6 +227,7 @@ def run_sync(*, mesh: str = "2x2x2", policy: str = "fsdp",
     composes with neither overlap (the pending would cross a membership
     boundary) nor the ring wire (W is baked into every hop)."""
     import jax
+    from repro.launch.mesh import make_mesh
     import jax.numpy as jnp
     import numpy as np
 
@@ -240,7 +238,7 @@ def run_sync(*, mesh: str = "2x2x2", policy: str = "fsdp",
     from repro.models import param as pm
 
     dims, axes = _parse_mesh(mesh)
-    jmesh = jax.make_mesh(dims, axes)
+    jmesh = make_mesh(dims, axes)
     if membership and (overlap or wire == "ring-int8"):
         raise ValueError("--membership composes with neither --overlap nor "
                          "the ring wire (run_sync docstring)")
@@ -440,6 +438,7 @@ def run_engine(*, mesh: str = "2x2x2", policy: str = "fsdp",
     — one quantization level, bounded per round by `ring_tolerance` of the
     (h·lr)-bounded local-step delta."""
     import jax
+    from repro.launch.mesh import make_mesh
     import numpy as np
 
     from repro.configs import registry as R
@@ -451,7 +450,7 @@ def run_engine(*, mesh: str = "2x2x2", policy: str = "fsdp",
     from repro.models import param as pm
 
     dims, axes = _parse_mesh(mesh)
-    jmesh = jax.make_mesh(dims, axes)
+    jmesh = make_mesh(dims, axes)
     cfg = R.get_smoke_config(arch)
     run_cfg = RunConfig(schedule="qsr", optimizer="adamw",
                         total_steps=2 * rounds, peak_lr=3e-3, end_lr=1e-6,
@@ -537,12 +536,13 @@ def probe() -> dict:
     devices.  tests/test_multihost.py runs this first and skips gracefully
     when the distributed CPU backend is unavailable."""
     import jax
+    from repro.launch.mesh import make_mesh
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n = len(jax.devices())
-    jmesh = jax.make_mesh((n,), ("x",))
+    jmesh = make_mesh((n,), ("x",))
     host = np.arange(n, dtype=np.float32)
     arr = jax.make_array_from_callback(
         (n,), NamedSharding(jmesh, P("x")), lambda idx: host[idx])
@@ -687,6 +687,7 @@ def run_elastic_worker(*, rounds: int, start_round: int = 0, workdir: str,
     single-process run of the same mesh is the bitwise reference for any
     multi-process generation (quantized sync: integer-code domain)."""
     import jax
+    from repro.launch.mesh import make_mesh
     import numpy as np
 
     from repro.configs import registry as R
@@ -695,7 +696,7 @@ def run_elastic_worker(*, rounds: int, start_round: int = 0, workdir: str,
     from repro.optim.lr import make_lr_fn
 
     workers = len(jax.devices())
-    jmesh = jax.make_mesh((workers, 1), ("data", "model"))
+    jmesh = make_mesh((workers, 1), ("data", "model"))
     cfg = R.get_smoke_config(arch)
     run_cfg = RunConfig(schedule="constant", optimizer="adamw",
                         total_steps=2 * max(rounds, 1), peak_lr=3e-3,

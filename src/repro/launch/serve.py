@@ -129,6 +129,8 @@ def run_service(cfg, weights, prompts, *, slots: int, max_new: int,
 
 def main():
     from repro.launch import multihost
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     multihost.initialize()  # no-op unless REPRO_COORDINATOR is set
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-4b")

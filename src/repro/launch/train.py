@@ -180,6 +180,8 @@ def train(cfg, run_cfg: RunConfig, *, workers: int, b_loc: int, seq: int,
 
 def main():
     from repro.launch import multihost
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     distributed = multihost.initialize()  # no-op without REPRO_COORDINATOR
     if distributed:
         print(f"multihost: {multihost.runtime_info()}")
@@ -276,9 +278,8 @@ def main():
         sync_wire=args.wire)
     mesh = None
     if args.mesh:
-        import jax
-        dims, axes = multihost._parse_mesh(args.mesh)
-        mesh = jax.make_mesh(dims, axes)
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh(*multihost._parse_mesh(args.mesh))
     eng = RoundEngine(cfg, run_cfg, workers=args.workers, b_loc=args.batch,
                       seq=args.seq, mode=args.engine, data=args.data,
                       layout=args.param_layout, sync=args.sync,

@@ -25,29 +25,17 @@ def scan_unroll():
 
 
 def shard_map_compat(f, mesh, *, in_specs, out_specs, manual_axes=None):
-    """`shard_map` across jax versions.
+    """`jax.shard_map` over the named *manual* axes (None = every mesh axis).
 
-    Newer jax exposes `jax.shard_map(..., axis_names=..., check_vma=...)`;
-    0.4.x has `jax.experimental.shard_map.shard_map(..., auto=...,
-    check_rep=...)` where `auto` is the complement of the manual axes.
-    Callers name the *manual* axes (None = all mesh axes manual) and this
-    shim translates.  On 0.4.x the partial-manual form (`auto=...`) trips an
-    XLA SPMD-partitioner check on the CPU backend, so there every axis goes
-    manual: axes the specs never mention are then implicitly replicated,
-    which is semantically identical for bodies whose collectives only touch
-    the manual axes.  Replication checking is disabled either way: the call
-    sites use psum_scatter/all_gather/all_to_all patterns the checker cannot
-    always infer through.
+    Replication checking is disabled: the call sites use
+    psum_scatter/all_gather/all_to_all patterns the checker cannot always
+    infer through.
     """
     manual = (frozenset(mesh.axis_names) if manual_axes is None
               else frozenset(manual_axes))
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, axis_names=manual,
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(f, mesh=mesh, axis_names=manual,
+                         in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 # --------------------------------------------------------------------------

@@ -33,6 +33,7 @@ from repro.core import engine as E
 from repro.core import flat as F
 from repro.core import schedules
 from repro.core.sync import make_sync, make_sync_begin, make_sync_partial
+from repro.launch.mesh import make_mesh
 from repro.optim.lr import make_lr_fn
 
 
@@ -239,7 +240,7 @@ def test_membership_epoch_guards():
 def test_membership_resize_refused_under_mesh():
     """Mesh-backed engines resize via checkpoint + respawn, never in place
     (jax.distributed cannot shrink a live process group)."""
-    jmesh = jax.make_mesh((1, 1), ("data", "model"))
+    jmesh = make_mesh((1, 1), ("data", "model"))
     eng, _ = _mk_engine(workers=1, mesh=jmesh, policy="dp")
     st = eng.init_state()
     with pytest.raises(E.MembershipError, match="respawn"):

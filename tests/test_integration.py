@@ -96,7 +96,8 @@ from repro.models import api, param as pm
 from repro.launch.shapes import _state_specs, _batch_specs, _ns
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = R.get_smoke_config("starcoder2-3b")
 run = RunConfig(optimizer="adamw", remat=False)
 mod = api.get_module(cfg)
@@ -171,7 +172,8 @@ from repro.models import api, moe, param as pm
 from repro.launch.shapes import _state_specs, _batch_specs, _ns
 
 import dataclasses
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 # aux load-balance loss uses per-shard statistics under expert parallelism
 # (a different, equally valid estimator) -> disable it for exact comparison
 cfg = dataclasses.replace(R.get_smoke_config("kimi-k2-1t-a32b"),
