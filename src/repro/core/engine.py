@@ -44,6 +44,12 @@ averaged over the round's valid steps, and the pre-sync worker divergence
 `mean_i ||x_i - x_bar||_2` — the quantity the paper's SDE analysis ties to
 the generalization benefit of large H.
 
+The round's parts run under `jax.named_scope`s — `grad` (forward and
+backward of the local step), `optimizer`, `telemetry` (the grad-norm sums
+and `_metrics`) and `sync` — so each op of the compiled round names, in its
+HLO `op_name`, the part it belongs to; `run_round` puts host spans
+(`repro.engine.*`) around its own work.  Neither changes the program.
+
 ## Param layouts
 
 `layout="flat"` carries the run state as FlatParamSpace dtype buckets
@@ -100,6 +106,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from typing import Any, Callable, Sequence
 
 import jax
@@ -241,10 +248,11 @@ def worker_divergence(params: Pytree) -> jax.Array:
 
 
 def _metrics(state, losses, gns, denom):
-    div = worker_divergence(state["params"])
-    return {"loss": jnp.sum(losses) / denom,
-            "grad_norm": jnp.sum(gns) / denom,
-            "divergence": div}
+    with jax.named_scope("telemetry"):
+        div = worker_divergence(state["params"])
+        return {"loss": jnp.sum(losses) / denom,
+                "grad_norm": jnp.sum(gns) / denom,
+                "divergence": div}
 
 
 # --------------------------------------------------------------------------
@@ -634,8 +642,11 @@ class RoundEngine:
                                           self.workers, self.b_loc, self.seq))
         self.spec = None                           # FlatParamSpace (layout="flat")
         self._programs: dict[int, Any] = {}
+        self._unlaunched: dict[Any, tuple] = {}    # built, not yet called
         self.compiles = 0
         self.cache_hits = 0
+        self.compile_s = 0.0                       # first calls of programs
+        self.compiled_at: list[tuple[int, tuple]] = []   # (t, key)
         self.h_trace: list[tuple[int, int]] = []   # (t_start, h) executed
 
     # -- state ------------------------------------------------------------
@@ -781,12 +792,19 @@ class RoundEngine:
             fn = self._keep_state_layout(fn)
         jit_kw = {"donate_argnums": donate} if self.donate else {}
         self._programs[key] = jax.jit(fn, **jit_kw)
+        self._unlaunched[self._programs[key]] = key
         self.compiles += 1
         return self._programs[key]
 
     def compile_stats(self) -> dict:
+        """compiles / cache_hits: `_program` builds and reuses; compile_s:
+        host seconds in the first calls of new programs (trace, lower, and
+        compile or persistent-cache load); compiled_at: (t, key) of the
+        round each program was first called in."""
         return {"compiles": self.compiles, "cache_hits": self.cache_hits,
-                "programs": sorted(self._programs)}
+                "programs": sorted(self._programs),
+                "compile_s": self.compile_s,
+                "compiled_at": list(self.compiled_at)}
 
     # -- execution --------------------------------------------------------
 
@@ -795,12 +813,30 @@ class RoundEngine:
 
         Returns (state, metrics) where metrics holds device scalars
         {"loss", "grad_norm", "divergence"} computed in-graph.
+
+        Host spans on the profiler's clock, each carrying t and h as
+        arguments: `repro.engine.args` while the round's arguments are
+        made, then `repro.engine.launch` around the jitted call —
+        `repro.engine.compile` on a program's first call, whose seconds add
+        to `compile_stats()["compile_s"]`.
         """
-        fn, args = self._round_call(t, h, lr_fn)
-        if self.sync_mode == "overlap":
-            state, self._pending, metrics = fn(state, *args)
+        span = jax.profiler.TraceAnnotation
+        with span("repro.engine.args", t=t, h=h):
+            fn, args = self._round_call(t, h, lr_fn)
+        key = self._unlaunched.pop(fn, None)
+        if key is None:
+            with span("repro.engine.launch", t=t, h=h):
+                out = fn(state, *args)
         else:
-            state, metrics = fn(state, *args)
+            t_call = time.perf_counter()
+            with span("repro.engine.compile", t=t, h=h):
+                out = fn(state, *args)
+            self.compile_s += time.perf_counter() - t_call
+            self.compiled_at.append((t, key))
+        if self.sync_mode == "overlap":
+            state, self._pending, metrics = out
+        else:
+            state, metrics = out
         self.h_trace.append((t, h))
         return state, metrics
 

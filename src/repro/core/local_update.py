@@ -103,9 +103,12 @@ def make_local_step(cfg, run_cfg, *, with_metrics: bool = False, spec=None,
 
     mb = getattr(run_cfg, "microbatch", 1)
 
+    @jax.named_scope("grad")
     def _value_and_grad(grad_fn, params, batch):
         """Per-worker loss/grad, optionally microbatched (grad accumulation
-        over `mb` sequential chunks — peak activation memory / mb)."""
+        over `mb` sequential chunks — peak activation memory / mb).  Runs
+        under the `grad` scope, so a profile can tell the local step's
+        forward and backward (`transpose(...)` in an op's path) apart."""
         if mb <= 1:
             return grad_fn(params, batch)
         chunks = jax.tree.map(
@@ -143,14 +146,18 @@ def make_local_step(cfg, run_cfg, *, with_metrics: bool = False, spec=None,
             losses, grads = jax.vmap(partial(_value_and_grad, grad_fn))(
                 state["params"], batch)
         # optimizer update is elementwise -> applies across the W axis as-is
-        params, opt_state = opt.update(state["params"], state["opt"], grads, lr)
+        with jax.named_scope("optimizer"):
+            params, opt_state = opt.update(state["params"], state["opt"],
+                                           grads, lr)
         new_state = {**state, "params": params, "opt": opt_state}
         if not with_metrics:
             return new_state, jnp.mean(losses)
-        sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)),
-                         axis=tuple(range(1, g.ndim)))
-                 for g in jax.tree.leaves(grads))       # [W]
-        return new_state, (jnp.mean(losses), jnp.mean(jnp.sqrt(sq)))
+        with jax.named_scope("telemetry"):
+            sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)),
+                             axis=tuple(range(1, g.ndim)))
+                     for g in jax.tree.leaves(grads))       # [W]
+            gn = jnp.mean(jnp.sqrt(sq))
+        return new_state, (jnp.mean(losses), gn)
 
     return local_step
 
@@ -186,8 +193,11 @@ def make_parallel_step(cfg, run_cfg):
     opt = make_optimizer(run_cfg)
 
     def step(state, batch, lr):
-        loss, grads = jax.value_and_grad(loss_fn)(state["params"], batch)
-        params, opt_state = opt.update(state["params"], state["opt"], grads, lr)
+        with jax.named_scope("grad"):
+            loss, grads = jax.value_and_grad(loss_fn)(state["params"], batch)
+        with jax.named_scope("optimizer"):
+            params, opt_state = opt.update(state["params"], state["opt"],
+                                           grads, lr)
         return {"params": params, "opt": opt_state}, loss
 
     return step

@@ -305,7 +305,8 @@ def main():
           f"{n_sync} communication rounds for {args.steps} steps "
           f"(comm volume {n_sync/args.steps:.1%} of data-parallel); "
           f"{cs['compiles']} XLA round programs "
-          f"(buckets {cs['programs']}, {cs['cache_hits']} cache hits)")
+          f"(buckets {cs['programs']}, {cs['cache_hits']} cache hits, "
+          f"{cs['compile_s']:.1f}s compiling)")
 
 
 if __name__ == "__main__":
