@@ -100,7 +100,15 @@ def attn_defs(cfg: ModelConfig) -> dict:
 
 
 def _attn_chunked(q, k, v, *, causal, window, prefix_len, q_offset, q_block=512):
-    """Block the query dim so the [Sq,Sk] score tile stays bounded."""
+    """Block the query dim so the [Sq,Sk] score tile stays bounded.
+
+    The scan body is checkpointed: the backward recomputes each chunk's
+    scores and f32 softmax from (qi, k, v) rather than stacking
+    [nblk, B, Hkv, G, q_block, Sk] probabilities and masks for the scan's
+    transpose.  One more QK^T and softmax pass per chunk costs less than
+    writing and reloading that memory-bound stack.  Without
+    differentiation the checkpoint is the plain body.
+    """
     b, sq, hq, hd = q.shape
     if sq <= q_block:
         return kops.flash_attention(q, k, v, causal=causal, window=window,
@@ -117,7 +125,8 @@ def _attn_chunked(q, k, v, *, causal, window, prefix_len, q_offset, q_block=512)
                                  q_offset=q_offset + i * q_block)
         return carry, o
 
-    _, outs = jax.lax.scan(body, 0, (jnp.arange(nblk), qs),
+    _, outs = jax.lax.scan(jax.checkpoint(body, prevent_cse=False), 0,
+                           (jnp.arange(nblk), qs),
                            unroll=scan_unroll())
     return outs.swapaxes(0, 1).reshape(b, sq, hq, hd)
 

@@ -1,5 +1,6 @@
 """Model-stack invariants: decode==forward consistency, SSD==naive recurrence,
-MoE dispatch conservation, RoPE shift property, masks."""
+MoE dispatch conservation, RoPE shift property, masks, chunked attention
+gradients and residuals."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -197,3 +198,57 @@ def test_gemma3_window_pattern():
     assert all(w == 1024 for i, w in enumerate(wins) if (i + 1) % 6 != 0)
     n_global = sum(w == 0 for w in wins)
     assert n_global == cfg.n_layers // 6  # 5:1 local:global
+
+
+# ------------------------------------------------- chunked attention grads --
+
+def _qkv(sq, dtype, hq=8, hkv=2, d=16, b=2):
+    ks = jax.random.split(jax.random.PRNGKey(sq), 3)
+    q = jax.random.normal(ks[0], (b, sq, hq, d)).astype(dtype)
+    k = jax.random.normal(ks[1], (b, sq, hkv, d)).astype(dtype)
+    v = jax.random.normal(ks[2], (b, sq, hkv, d)).astype(dtype)
+    return q, k, v
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("window", [0, 24])
+@pytest.mark.parametrize("sq,q_block", [(64, 16), (60, 16)])
+def test_chunked_attention_grad_matches_full(sq, q_block, window, dtype, tol):
+    """Gradients through the query-chunk scan (its body recomputed in the
+    backward) equal those of one attention over the whole sequence; 60/16
+    takes the largest-divisor fallback (q_block 15)."""
+    from repro.kernels import ref
+    q, k, v = _qkv(sq, dtype)
+    w = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+
+    chunked = loss(lambda q, k, v: cm._attn_chunked(
+        q, k, v, causal=True, window=window, prefix_len=0, q_offset=0,
+        q_block=q_block))
+    full = loss(lambda q, k, v: ref.attention(q, k, v, causal=True,
+                                              window=window))
+    got = jax.grad(chunked, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(full, argnums=(0, 1, 2))(q, k, v)
+    for g_, w_ in zip(got, want):
+        g_, w_ = np.asarray(g_, np.float32), np.asarray(w_, np.float32)
+        scale = np.abs(w_).max()
+        np.testing.assert_allclose(g_, w_, rtol=tol, atol=tol * scale)
+
+
+def test_chunked_attention_saves_no_score_stack():
+    """The backward keeps no [nblk, B, Hkv, G, q_block, Sk] stack of the
+    chunks' scores, softmax or mask: each chunk is recomputed."""
+    sq, q_block, hq, hkv = 64, 16, 8, 2
+    q, k, v = _qkv(sq, jnp.float32, hq=hq, hkv=hkv)
+    b = q.shape[0]
+    _, vjp = jax.vjp(lambda q, k, v: cm._attn_chunked(
+        q, k, v, causal=True, window=0, prefix_len=0, q_offset=0,
+        q_block=q_block), q, k, v)
+    stack = (sq // q_block, b, hkv, hq // hkv, q_block, sq)
+    shapes = [tuple(x.shape) for x in jax.tree_util.tree_leaves(vjp)]
+    assert shapes, "the VJP closure exposes no residuals"
+    assert stack not in shapes, shapes
