@@ -23,7 +23,7 @@ import time
 
 import jax
 
-from bench import compare, flops, manifest, peaks, trace
+from bench import compare, flops, manifest, peaks, scopes, trace
 from bench.reference import common as C
 from bench.reference.train import Reference
 
@@ -137,14 +137,18 @@ def reference_readings(cell, seed: int, ref: Reference) -> dict:
     return ref.run(params0, pool, t0=t0, rounds=rounds)
 
 
-def program_bytes(sys_, state, t: int) -> dict:
-    """The memory_analysis of the round program the window drives, per
-    device: arguments, outputs not aliased to them, temporaries and code.
-    A program the window ran is found in JAX's caches, not compiled anew."""
+def compiled_round(sys_, state, t: int):
+    """The compiled round program the window drives.  A program the window
+    ran is found in JAX's caches, not compiled anew."""
     shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
         x.shape, x.dtype, sharding=x.sharding), state)
-    ma = sys_.eng.compiled_round(shapes, t, sys_.get_h(t),
-                                 sys_.lr_fn).memory_analysis()
+    return sys_.eng.compiled_round(shapes, t, sys_.get_h(t), sys_.lr_fn)
+
+
+def program_bytes(compiled) -> dict:
+    """The memory_analysis of a compiled program, per device: arguments,
+    outputs not aliased to them, temporaries and code."""
+    ma = compiled.memory_analysis()
     return {"argument": ma.argument_size_in_bytes,
             "output": ma.output_size_in_bytes,
             "alias": ma.alias_size_in_bytes,
@@ -152,7 +156,7 @@ def program_bytes(sys_, state, t: int) -> dict:
             "code": ma.generated_code_size_in_bytes}
 
 
-def peak_bytes(sys_, state, t: int) -> tuple[int, dict]:
+def peak_bytes(sys_, state, compiled) -> tuple[int, dict]:
     """Peak bytes on the fullest device while a round runs.  The
     allocator's `peak_bytes_in_use` does not count a program's
     temporaries, so each device's peak is the larger of it and of what
@@ -160,7 +164,7 @@ def peak_bytes(sys_, state, t: int) -> tuple[int, dict]:
     the state, which is the program's argument) plus the program's own
     footprint from its memory_analysis.  Returns (bytes, the readings)."""
     stats = {d: d.memory_stats() or {} for d in sys_.devices}
-    prog = program_bytes(sys_, state, t)
+    prog = program_bytes(compiled)
     footprint = (prog["argument"] + prog["output"] - prog["alias"]
                  + prog["temp"] + prog["code"])
     state_on = {d: 0 for d in sys_.devices}
@@ -217,7 +221,11 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     if sys_.get_h(win["t_end"]) != h0:
         log(f"H moved from {h0} to {sys_.get_h(win['t_end'])} by the end")
     t_mem = time.perf_counter()
-    peak, mem = peak_bytes(sys_, state, t)
+    compiled = compiled_round(sys_, state, t)
+    peak, mem = peak_bytes(sys_, state, compiled)
+    collectives = (scopes.sync_collectives(compiled.as_text()) if traced
+                   else frozenset())
+    del compiled
     used = sys_.devices
     log(f"memory ({time.perf_counter() - t_mem:.1f}s): {mem}")
     del state
@@ -247,7 +255,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     if traced:
         events = trace.compact(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        summ = trace.summarize(events)
+        summ = trace.summarize(events, collectives)
         record["trace"] = summ
         record["peak_flops"] = peaks.peaks(used[0].device_kind)["bf16_flops"]
         busy = [summ["busy_s"][str(d.id)] for d in used

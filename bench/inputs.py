@@ -50,10 +50,13 @@ def tokens_per_example(kind: str, conf: dict, traffic: dict) -> int:
 
 
 def make_pool(kind: str, conf: dict, traffic: dict, workers: int,
-              seed: int) -> list[dict]:
-    """The pool for `seed`, on the default device."""
+              seed: int, sharding=None) -> list[dict]:
+    """The pool for `seed`, on the default device; with `sharding` (of the
+    leading worker axis over a mesh) each chip makes its own workers'
+    batches.  The values do not depend on where they are made."""
     one = GENERATORS[kind](conf, traffic, workers)
     steps = traffic["pool_steps"]
+    kw = {} if sharding is None else {"out_shardings": sharding}
     fn = jax.jit(lambda key: [one(jax.random.fold_in(key, i))
-                              for i in range(steps)])
+                              for i in range(steps)], **kw)
     return fn(seed_key(seed, INPUTS))

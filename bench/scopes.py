@@ -14,7 +14,6 @@ to the device-idle time under each name per round.  Times in seconds.
 """
 from __future__ import annotations
 
-import bisect
 import glob
 import os
 import re
@@ -62,10 +61,18 @@ def op_parts(hlo_text: str) -> dict[str, str]:
     return out
 
 
-def instruction(event_name: str) -> str:
-    """The instruction an op event names: the first token of the event's
-    name (`%fusion.72 = (f32[...]) fusion(...)` -> `fusion.72`)."""
-    return event_name.split(" ", 1)[0].lstrip("%")
+def sync_collectives(hlo_text: str) -> frozenset[str]:
+    """The collectives of an HLO module's text (trace.collective_ops) that
+    are not in the `telemetry` scope: the sync's exchanges, those that lost
+    their `op_name` in lowering (the sync's reduce-scatter among them)
+    included, and not the divergence telemetry's all-reduce of the
+    parameters."""
+    parts = op_parts(hlo_text)
+    return frozenset(op for op in trace.collective_ops(hlo_text)
+                     if parts.get(op) != "telemetry")
+
+
+instruction = trace.instruction
 
 
 def engine_spans(events: dict, profile_dir: str) -> dict:
@@ -112,12 +119,7 @@ def round_ops(events: dict) -> tuple[dict[str, float], int]:
     round program in the window, on its first device."""
     names = events["names"]
     _, _, first, runs = _window_and_runs(events)
-    starts = [s for s, _ in runs]
-    inside = []
-    for e in events["devices"][first]["ops"]:
-        i = bisect.bisect_right(starts, e[1]) - 1
-        if i >= 0 and e[1] < runs[i][1]:
-            inside.append(e)
+    inside = trace._in_runs(events["devices"][first]["ops"], runs)
     out: dict[str, float] = {}
     for n, t in trace._self_times(inside).items():
         op = instruction(names[n])

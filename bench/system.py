@@ -4,8 +4,15 @@ RoundEngine with its QSR schedule, fed through its host-data hook
 
 Everything the benchmark takes from the program passes through here: the
 engine and its `run_round`, `compile_stats` and flat-layout `spec`, the
-schedule's `get_h` and learning rate.  Every cell runs its workers as
-lanes on one chip.
+schedule's `get_h` and learning rate.
+
+A cell without a `mesh` in its traffic runs its workers as lanes on one
+chip.  A cell with one builds that mesh of chips through the program's
+`launch/mesh.make_mesh` and hands it, with the traffic's sharding policy,
+to the engine, which lays the worker-stacked state out over the mesh's
+worker axis itself (from one worker's state, built on the host); the
+weights are made replicated over the mesh and the input pool split over
+its worker axis, so that each chip's batch is made on that chip.
 """
 from __future__ import annotations
 
@@ -43,9 +50,19 @@ class System:
             weight_decay=opt["weight_decay"], remat=traffic["remat"])
         self.lr_fn = make_lr_fn(self.run_cfg)
         self.get_h = lambda t: schedules.get_h(self.run_cfg, t, self.lr_fn)
-        if traffic.get("mesh"):
-            raise ValueError("cells on a mesh of chips are not supported")
-        self.devices = jax.devices()[:1]
+        mesh, self.mesh, engine_kw = traffic.get("mesh"), None, {}
+        if mesh:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            from repro.launch.mesh import make_mesh
+            from repro.models import param as pm
+            self.mesh = make_mesh(mesh["shape"], mesh["axes"])
+            engine_kw = {"mesh": self.mesh, "policy": mesh["policy"]}
+            self.devices = list(self.mesh.devices.flat)
+            self._replicated = NamedSharding(self.mesh, P())
+            self._by_worker = NamedSharding(self.mesh, P(
+                pm.worker_mesh_axes(mesh["policy"], self.mesh)))
+        else:
+            self.devices = jax.devices()[:1]
         self.pool = None
 
         def batch_fn(step):
@@ -58,23 +75,37 @@ class System:
         self.eng = RoundEngine(
             self.cfg, self.run_cfg, workers=self.workers, b_loc=self.b_loc,
             seq=self.tokens_per_example, data="host", batch_fn=batch_fn,
-            layout=traffic["layout"])
+            layout=traffic["layout"], **engine_kw)
 
     # -- inputs and state --------------------------------------------------
 
     def params(self, seed: int):
-        """The initial weights, made on the device in one jitted call."""
+        """The initial weights, made on the device in one jitted call;
+        on a mesh, replicated over its chips."""
         shapes = self.cell.model.param_shapes(self.cell.conf)
-        return jax.jit(lambda key: C.init_params(shapes, key))(
+        kw = {"out_shardings": self._replicated} if self.mesh else {}
+        return jax.jit(lambda key: C.init_params(shapes, key), **kw)(
             C.seed_key(seed, C.WEIGHTS))
 
     def init(self, seed: int):
         """Engine state and input pool for `seed`."""
-        self.pool = inputs.make_pool(self.kind, self.cell.conf,
-                                     self.cell.traffic, self.workers, seed)
-        shapes = self.cell.model.param_shapes(self.cell.conf)
-        state = jax.jit(lambda key: self.eng.init_state(
-            C.init_params(shapes, key)))(C.seed_key(seed, C.WEIGHTS))
+        self.pool = inputs.make_pool(
+            self.kind, self.cell.conf, self.cell.traffic, self.workers, seed,
+            sharding=self._by_worker if self.mesh else None)
+        if self.mesh:
+            # the engine lays one worker's state out over the mesh through
+            # the host (flat.make_global), after building that state with
+            # eager ops on the default device: on a chip, its transient
+            # copies set an allocator peak that hangs on timing.  So the
+            # state is built on the host's CPU, and no copy of it passes
+            # through a chip before its shards
+            weights = jax.device_get(self.params(seed))
+            with jax.default_device(jax.devices("cpu")[0]):
+                state = self.eng.init_state(weights)
+        else:
+            shapes = self.cell.model.param_shapes(self.cell.conf)
+            state = jax.jit(lambda key: self.eng.init_state(
+                C.init_params(shapes, key)))(C.seed_key(seed, C.WEIGHTS))
         return jax.block_until_ready(state)
 
     def release(self):

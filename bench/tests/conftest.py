@@ -42,6 +42,11 @@ TINY_TRAFFIC = {
                               "end_lr": 1e-6, "warmup_steps": 10,
                               "total_steps": 10000000}},
 }
+# the four-worker decoder traffic on a 4x1 data-parallel mesh, one worker a
+# device: needs four devices (XLA_FLAGS=--xla_force_host_platform_device_count=4)
+TINY_TRAFFIC["tiny-h2-dp4"] = dict(
+    TINY_TRAFFIC["tiny-h2"], workers=4, layout="flat_sharded",
+    mesh={"shape": [4, 1], "axes": ["data", "model"], "policy": "dp"})
 
 
 def _limits(cell: str) -> dict:
@@ -54,6 +59,10 @@ TINY_CELLS = {
     "tiny-decoder.h2": ("tiny-decoder", "tiny-h2",
                         _limits("starcoder2-3b-L1.h2.1chip")),
     "tiny-vit.h4": ("tiny-vit", "tiny-img", _limits("vit-b16.h4.1chip")),
+}
+MESH_CELLS = {
+    "tiny-decoder.h2.dp4": ("tiny-decoder", "tiny-h2-dp4",
+                            _limits("starcoder2-3b-L1.h2.4chip")),
 }
 
 
@@ -89,8 +98,10 @@ def write_benchmark(root, cells=TINY_CELLS) -> None:
             json.dump(TINY_TRAFFIC[traffic], f)
         with open(os.path.join(base, "cells", name + ".json"), "w") as f:
             json.dump(cell, f)
+        mesh = TINY_TRAFFIC[traffic]["mesh"]
+        chips = mesh["shape"][0] * mesh["shape"][1] if mesh else 1
         man["workloads"].append({"name": name, "config": conf,
-                                 "traffic": traffic, "chips": 1,
+                                 "traffic": traffic, "chips": chips,
                                  "why": "test"})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(man, f)

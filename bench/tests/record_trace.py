@@ -7,7 +7,11 @@
 Makes one `--trace 1` run of the cell on the chip, prints its result line
 as bench/run.py does, and writes the compact event list of the traced
 window (`trace.compact`), cut to its first `--rounds` round programs
-(`trace.cut`).
+(`trace.cut`).  Where the round program exchanges data between chips, the
+list also holds `collectives`, the instructions `trace.collective_ops`
+read from the compiled round, and `parts`, the part of the round each of
+them belongs to by the program's scopes (`scopes.op_parts`); the collective
+time of each part is printed to standard error.
 """
 from __future__ import annotations
 
@@ -31,22 +35,39 @@ def main(argv=None) -> int:
     ap.add_argument("--out", required=True)
     args = ap.parse_args(argv)
     sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
-    from bench import harness, trace
+    from bench import harness, scopes, trace
 
     kept = {}
-    compact = trace.compact
+    compact, collective_ops = trace.compact, trace.collective_ops
 
     def keep(profile_dir):
         kept["events"] = compact(profile_dir)
         return kept["events"]
 
-    trace.compact = keep
+    def keep_ops(hlo_text):
+        kept["parts"] = scopes.op_parts(hlo_text)
+        kept["collectives"] = collective_ops(hlo_text)
+        return kept["collectives"]
+
+    trace.compact, trace.collective_ops = keep, keep_ops
     harness.check_chips(1)
     harness.enable_compile_cache()
     result = harness.run_cell(ROOT, args.workload, args.seed, args.seconds,
                               True, t_process=T_PROCESS)
+    events = trace.cut(kept["events"], args.rounds)
+    if kept["collectives"]:
+        events["collectives"] = sorted(kept["collectives"])
+        events["parts"] = {op: kept["parts"].get(op, "other")
+                           for op in events["collectives"]}
+        by_part = {}
+        for op, t in scopes.round_ops(events)[0].items():
+            if op in kept["collectives"]:
+                part = events["parts"][op]
+                by_part[part] = by_part.get(part, 0.0) + t
+        harness.log(f"collective self time by part over {args.rounds} "
+                    f"rounds, first device: {by_part}")
     with open(args.out, "w") as f:
-        json.dump(trace.cut(kept["events"], args.rounds), f)
+        json.dump(events, f)
     print(json.dumps(result), flush=True)
     return 0
 

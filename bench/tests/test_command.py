@@ -37,3 +37,23 @@ def test_refuses_with_only_the_benchmark(tmp_path):
     out = _run(str(tmp_path))
     assert out.returncode != 0
     assert not _printed_result(out.stdout)
+
+
+def test_a_cell_refuses_fewer_chips_than_it_asks_for(tmp_path, monkeypatch):
+    """bench/run.py checks for one chip before it knows the cell; the
+    cell's own check refuses a machine with fewer chips than it asks for."""
+    import jax
+    import pytest
+
+    from bench import harness
+    from bench.tests.conftest import MESH_CELLS, write_benchmark
+
+    class OneTpu:
+        platform, device_kind, id = "tpu", "TPU v5 lite", 0
+
+    write_benchmark(str(tmp_path), MESH_CELLS)
+    monkeypatch.setattr(jax, "devices", lambda *a: [OneTpu()])
+    harness.check_chips(1)
+    with pytest.raises(harness.ChipError, match="needs 4 chips"):
+        harness.run_cell(str(tmp_path), "tiny-decoder.h2.dp4", 1, 1.0,
+                         False, t_process=0.0)

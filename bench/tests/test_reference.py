@@ -56,7 +56,8 @@ def test_memory_peak_counts_the_round_programs_temporaries(tiny_root):
     sys_ = System(cell)
     state = sys_.init(5)
     state, t, _ = harness.first_rounds(sys_, state, 5)
-    peak, mem = harness.peak_bytes(sys_, state, t)
+    peak, mem = harness.peak_bytes(sys_, state,
+                                   harness.compiled_round(sys_, state, t))
     prog = mem["program"]
     assert prog["temp"] > 0 and prog["argument"] > 0
     assert peak >= prog["temp"]

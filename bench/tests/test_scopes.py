@@ -68,6 +68,19 @@ ENTRY %main.1 (p: f32[4]) -> f32[4] {
     assert scopes.instruction("dot.134") == "dot.134"
 
 
+def test_sync_collectives_leave_out_telemetry():
+    text = """HloModule jit_pinned
+ENTRY %main.1 (p: f32[4]) -> f32[4] {
+  %all-reduce.2 = f32[4]{0} all-reduce(%p), to_apply=%add, metadata={op_name="jit(pinned)/telemetry/reduce_sum"}
+  %all-reduce.4 = f32[4]{0} all-reduce(%p), to_apply=%add
+  %all-gather.4 = f32[16]{0} all-gather(%p), dimensions={0}, metadata={op_name="jit(pinned)/sync/all_gather"}
+  ROOT %fusion.3 = f32[4]{0} fusion(%p), kind=kLoop, calls=%fc, metadata={op_name="jit(pinned)/telemetry/sqrt"}
+}"""
+    assert trace.collective_ops(text) == {"all-reduce.2", "all-reduce.4",
+                                          "all-gather.4"}
+    assert scopes.sync_collectives(text) == {"all-reduce.4", "all-gather.4"}
+
+
 @pytest.mark.parametrize("name", sorted(TINY_CELLS))
 def test_compiled_round_names_every_part(tiny_root, name):
     sys_, state = _system(tiny_root, name)

@@ -3,9 +3,10 @@
 `compact` keeps what the reduction needs from the `.xplane.pb` file the
 JAX profiler writes: the device operations and program (module) runs of
 every TPU, and the harness's own host spans (names starting "bench.").
-`summarize` reduces that event list; it is checked on a recorded chip
-trace (bench/tests/data).  All times in a compact list are nanoseconds on
-the profiler's one clock.
+`summarize` reduces that event list; it is checked on recorded chip
+traces (bench/tests/data).  All times in a compact list are nanoseconds on
+the profiler's one clock.  Which ops are collectives is read from the
+compiled round program's text (`collective_ops`), by HLO opcode.
 """
 from __future__ import annotations
 
@@ -19,6 +20,59 @@ LINES = {"XLA Ops": "ops", "XLA Modules": "modules"}
 SPAN_PREFIX = "bench."
 TOP = 10
 NAME_CHARS = 100      # of an op's HLO text, in the breakdown
+
+COLLECTIVES = frozenset({
+    "all-reduce", "all-reduce-start", "all-reduce-done", "all-gather",
+    "all-gather-start", "all-gather-done", "reduce-scatter",
+    "collective-permute", "collective-permute-start",
+    "collective-permute-done", "all-to-all", "collective-broadcast"})
+# ops that run a computation of their own, which may hold a collective
+WRAPPERS = frozenset({"fusion", "async-start", "async-update", "async-done"})
+_HLO_OP = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?[\]})] ([a-z][a-z0-9\-]*)\(")
+_CALLS = re.compile(r"\bcalls=\{?(%?[\w.\-]+(?:,\s*%?[\w.\-]+)*)")
+
+
+def instruction(event_name: str) -> str:
+    """The instruction an op event names: the first token of the event's
+    name (`%fusion.72 = (f32[...]) fusion(...)` -> `fusion.72`)."""
+    return event_name.split(" ", 1)[0].lstrip("%")
+
+
+def collective_ops(hlo_text: str) -> frozenset[str]:
+    """The instructions of an HLO module's text that exchange data between
+    devices: those whose opcode is a collective (all-reduce, all-gather,
+    reduce-scatter, collective-permute, all-to-all, their async start and
+    done halves), and fusions and async wrappers whose computation holds
+    one."""
+    comps: dict[str, list] = {}
+    body: list = []
+    for line in hlo_text.splitlines():
+        if line[:1] not in ("", " ", "\t") and line.rstrip().endswith("{"):
+            body = comps.setdefault(
+                line.removeprefix("ENTRY ").split()[0].lstrip("%"), [])
+            continue
+        m = _HLO_OP.match(line)
+        if m:
+            c = _CALLS.search(line)
+            calls = ([x.strip().lstrip("%") for x in c.group(1).split(",")]
+                     if c else [])
+            body.append((m.group(1), m.group(2), calls))
+    memo: dict[str, bool] = {}
+
+    def exchanges(op: str, calls: list) -> bool:
+        return op in COLLECTIVES or (op in WRAPPERS and any(
+            holds(c) for c in calls))
+
+    def holds(comp: str) -> bool:
+        if comp not in memo:
+            memo[comp] = False
+            memo[comp] = any(exchanges(op, calls)
+                             for _, op, calls in comps.get(comp, ()))
+        return memo[comp]
+
+    return frozenset(name for insts in comps.values()
+                     for name, op, calls in insts if exchanges(op, calls))
 
 
 def compact(profile_dir: str) -> dict:
@@ -89,10 +143,10 @@ def _covered(merged: list, lo: int, hi: int) -> int:
     return total
 
 
-def _self_times(ops: list) -> dict[int, int]:
-    """{name: total self time} of one line's events, which nest: a while
-    or conditional op spans the ops of its body, so each event's self time
-    is its duration less that of the events directly inside it."""
+def _nesting(ops: list) -> tuple[list, list]:
+    """One line's events, which nest (a while or conditional op spans the
+    ops of its body), sorted by start, and the time of the events directly
+    inside each."""
     evs = sorted(ops, key=lambda e: (e[1], -e[2]))
     inner = [0] * len(evs)
     stack: list[int] = []
@@ -102,9 +156,58 @@ def _self_times(ops: list) -> dict[int, int]:
         if stack and s + du <= evs[stack[-1]][1] + evs[stack[-1]][2]:
             inner[stack[-1]] += du
         stack.append(i)
+    return evs, inner
+
+
+def _self_times(ops: list) -> dict[int, int]:
+    """{name: total self time} of one line's events: each event's duration
+    less that of the events directly inside it."""
     out: dict[int, int] = {}
-    for (n, _, du), c in zip(evs, inner):
+    for (n, _, du), c in zip(*_nesting(ops)):
         out[n] = out.get(n, 0) + du - c
+    return out
+
+
+def _leaves(ops: list) -> list:
+    """The events of one line that hold no other event: the work, not the
+    loops and conditionals around it."""
+    return [e for e, c in zip(*_nesting(ops)) if not c]
+
+
+def _in_runs(ops: list, runs: list) -> list:
+    """The events that start inside one of the sorted [start, end) runs."""
+    starts = [s for s, _ in runs]
+    out = []
+    for e in ops:
+        i = bisect.bisect_right(starts, e[1]) - 1
+        if i >= 0 and e[1] < runs[i][1]:
+            out.append(e)
+    return out
+
+
+def _collective_times(events: dict, lo: int, hi: int, round_name: int,
+                      collectives: frozenset) -> dict:
+    """{device: (collective ns, exposed ns) per run of the round program}:
+    the union of the device's collective ops inside its runs of the round
+    program in [lo, hi), and the part of it in which no other op (of the
+    work, not a loop around it) runs on that device."""
+    names = events["names"]
+    out = {}
+    for d, dev in events["devices"].items():
+        runs = sorted([s, s + du] for n, s, du in dev["modules"]
+                      if n == round_name and s >= lo and s + du <= hi)
+        if not runs:
+            continue
+        inside = _in_runs(dev["ops"], runs)
+        is_coll = [instruction(names[e[0]]) in collectives for e in inside]
+        coll = _merge([[s, s + du] for (_, s, du), c in zip(inside, is_coll)
+                       if c])
+        if not coll:
+            continue
+        work = _merge([[s, s + du] for _, s, du in _leaves(
+            [e for e, c in zip(inside, is_coll) if not c])])
+        exposed = sum(e - s - _covered(work, s, e) for s, e in coll)
+        out[d] = (_length(coll) / len(runs), exposed / len(runs))
     return out
 
 
@@ -112,10 +215,13 @@ def _self_times(ops: list) -> dict[int, int]:
 # The summary
 # --------------------------------------------------------------------------
 
-def summarize(events: dict) -> dict:
+def summarize(events: dict, collectives: frozenset = frozenset()) -> dict:
     """Reduce a compact event list to what the metrics read (seconds):
     window_s, busy_s per device, rounds, round_busy_s, round_gap_idle_s,
-    and the breakdown's device_ops and idle_gaps."""
+    the breakdown's device_ops and idle_gaps, and, over the instructions
+    `collectives` names, collective_s and collective_exposed_s per run of
+    the round program, each on the device where it is largest (None where
+    no collective ran), with collective_device and exposed_device."""
     names = events["names"]
     spans = [(names[n], s, s + d) for n, s, d in events["host"]]
     windows = [(s, e) for n, s, e in spans if n == "bench.window"]
@@ -158,6 +264,9 @@ def summarize(events: dict) -> dict:
         inner = [(e - s, n) for n, s, e in spans if s <= t < e]
         return min(inner)[1][len(SPAN_PREFIX):] if inner else "outside"
 
+    coll = _collective_times(events, lo, hi, round_name, collectives)
+    c_dev = max(coll, key=lambda d: coll[d][0], default=None)
+    x_dev = max(coll, key=lambda d: coll[d][1], default=None)
     return {
         "window_s": (hi - lo) / 1e9,
         "busy_s": {d: _length(m) / 1e9 for d, m in merged.items()},
@@ -169,6 +278,10 @@ def summarize(events: dict) -> dict:
         "device_ops": [[names[n][:NAME_CHARS], t / 1e9] for n, t in sorted(
             by_op.items(), key=lambda x: x[1], reverse=True)[:TOP]],
         "idle_gaps": [[doing((s + e) / 2), (e - s) / 1e9] for s, e in idle],
+        "collective_s": coll[c_dev][0] / 1e9 if coll else None,
+        "collective_device": c_dev,
+        "collective_exposed_s": coll[x_dev][1] / 1e9 if coll else None,
+        "exposed_device": x_dev,
     }
 
 
