@@ -22,6 +22,7 @@ import tempfile
 import time
 
 import jax
+import numpy as np
 
 from bench import compare, flops, manifest, peaks, scopes, trace
 from bench.reference import common as C
@@ -92,8 +93,10 @@ def first_rounds(sys_, state, seed: int):
 
 
 def window(sys_, state, t: int, seconds: float):
-    """Rounds until `seconds` have passed, as launch/train.py drives them.
-    Returns (state, stats)."""
+    """Rounds until `seconds` have passed, as launch/train.py drives them:
+    one scalar, the loss, read to the host a round.  Returns (state,
+    stats); stats' "counters" are the scalars of the last round's metrics,
+    read once the clock has stopped."""
     steps = rounds = bad = 0
     with span("bench.window"):
         t_start = time.perf_counter()
@@ -111,8 +114,9 @@ def window(sys_, state, t: int, seconds: float):
                 break
         jax.block_until_ready(state)
         elapsed = time.perf_counter() - t_start
+    counters = {k: float(v) for k, v in m.items() if np.ndim(v) == 0}
     return state, {"seconds": elapsed, "steps": steps, "rounds": rounds,
-                   "nonfinite_steps": bad, "t_end": t}
+                   "nonfinite_steps": bad, "t_end": t, "counters": counters}
 
 
 def reference(cell, devices, *, num=C.FLOAT32, faults=()) -> Reference:
@@ -184,6 +188,19 @@ def peak_bytes(sys_, state, compiled) -> tuple[int, dict]:
     return peak, {"program": prog, "devices": per_device}
 
 
+def read_metrics(cell, record: dict, traced: bool) -> dict:
+    """The cell's end-to-end metrics from the record, or, traced, its
+    per-layer metrics, each by its reader (metrics/<name>.py); a metric
+    whose reader finds nothing to read is left out."""
+    out = {}
+    for m in (cell.per_layer if traced else cell.end_to_end):
+        value = (manifest.metric_reader(m["name"])(record) if traced
+                 else record[m["name"]])
+        if value is not None:
+            out[m["name"]] = {"value": value, "unit": m["unit"]}
+    return out
+
+
 def run_cell(root: str, workload: str, seed: int, seconds: float,
              traced: bool, *, t_process: float,
              require_tpu: bool = True) -> dict:
@@ -214,7 +231,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     state, win = window(sys_, state, t, seconds)
     if traced:
         jax.profiler.stop_trace()
-    compiles = sys_.eng.compile_stats()["compiles"] - compiles0
+    stats = sys_.eng.compile_stats()
+    compiles = stats["compiles"] - compiles0
     if compiles:
         raise RuntimeError(f"{compiles} round programs compiled inside the "
                            "window: H left its warmed bucket")
@@ -223,8 +241,11 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     t_mem = time.perf_counter()
     compiled = compiled_round(sys_, state, t)
     peak, mem = peak_bytes(sys_, state, compiled)
-    collectives = (scopes.sync_collectives(compiled.as_text()) if traced
-                   else frozenset())
+    if traced:
+        text = compiled.as_text()
+        collectives, names = (scopes.sync_collectives(text),
+                              scopes.op_names(text))
+        del text
     del compiled
     used = sys_.devices
     log(f"memory ({time.perf_counter() - t_mem:.1f}s): {mem}")
@@ -251,11 +272,17 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
               "setup_s": setup_s, "setup_init_s": setup_init_s,
               "compiles_in_window": compiles, "chips": cell.chips,
               "flops_per_token": ex["flops"] / ex["tokens"],
-              "peak_flops": None, "trace": None}
+              "peak_flops": None, "trace": None,
+              # the engine's numeric compile stats and the last round's
+              # scalars
+              "counters": {**{k: float(v) for k, v in stats.items()
+                              if isinstance(v, (int, float))},
+                           **win["counters"]}}
     if traced:
         events = trace.compact(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        summ = trace.summarize(events, collectives)
+        summ = {**trace.summarize(events, collectives),
+                **scopes.summarize(events, names)}
         record["trace"] = summ
         record["peak_flops"] = peaks.peaks(used[0].device_kind)["bf16_flops"]
         busy = [summ["busy_s"][str(d.id)] for d in used
@@ -265,12 +292,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         result["device"]["window_s"] = summ["window_s"]
         result["breakdown"] = {"device_ops": summ["device_ops"],
                                "idle_gaps": summ["idle_gaps"]}
-    for m in (cell.per_layer if traced else cell.end_to_end):
-        value = (manifest.metric_reader(m["name"])(record) if traced
-                 else record[m["name"]])
-        if value is not None:
-            result["metrics"][m["name"]] = {"value": value,
-                                            "unit": m["unit"]}
+    result["metrics"] = read_metrics(cell, record, traced)
 
     ref = reference_readings(cell, seed, reference(cell, used))
     found = compare.gaps(prog, ref)

@@ -1,16 +1,19 @@
-"""The round program's device time by part, and the device's idle time
-under the engine's host spans.
+"""The round program's device time by part and by scope, and the device's
+idle time under the engine's host spans.
 
 The program names the parts of its round with `jax.named_scope`: `grad`
 (the local step's forward and backward), `optimizer`, `sync` and
 `telemetry`.  XLA keeps the scope path in each instruction's `op_name`
-metadata, and a fusion carries the path of its root op.  `op_parts` keys
+metadata, and a fusion carries the path of its root op.  `op_names` keys
 every instruction of the compiled round's text (`compiled.as_text()`) to
-its part; `split` reduces a compact event list (bench/trace.py) with that
-map to device self time per part and round.  The engine puts host spans
-named `repro.engine.*` around its own work (`RoundEngine.run_round`);
-`engine_spans` adds them to a compact list, and `engine_idle` reduces them
-to the device-idle time under each name per round.  Times in seconds.
+its `op_name`; `summarize` reduces a compact event list (bench/trace.py)
+with that map to device self time per round by part (`part_of`, the
+innermost of the four scopes) and by every scope on the ops' name stacks
+(`scope_names`), so that a scope the program adds is read with no change
+here.  The engine puts host spans named `repro.engine.*` around its own
+work (`RoundEngine.run_round`); `engine_spans` adds them to a compact
+list, and `engine_idle` reduces them to the device-idle time under each
+name per round.  Times in seconds.
 """
 from __future__ import annotations
 
@@ -20,6 +23,8 @@ import re
 
 from bench import trace
 
+# the scopes that make the parts; the parts' meaning is fixed, so a scope
+# added inside one of these (a `moe` inside `grad`) leaves the split as it is
 SCOPES = ("grad", "optimizer", "sync", "telemetry")
 PARTS = ("forward", "backward", "optimizer", "sync", "telemetry", "other")
 ENGINE_PREFIX = "repro."
@@ -29,6 +34,8 @@ _INSTRUCTION = re.compile(
 # a path component naming a scope, bare or inside transformations:
 # `sync`, `vmap(grad)`, `transpose(jvp(grad))`
 _SCOPE = re.compile(r"^(?:[\w\-]+\()*(%s)\)*$" % "|".join(SCOPES))
+# any name stack component, bare or inside transformations
+_NAME = re.compile(r"^(?:[\w\-]+\()*([^()]*)\)*$")
 
 
 def part_of(op_name: str) -> str:
@@ -50,15 +57,36 @@ def part_of(op_name: str) -> str:
     return "other"
 
 
-def op_parts(hlo_text: str) -> dict[str, str]:
-    """{instruction name: part} of every instruction in an HLO module's
-    text that carries an `op_name`; an instruction without one is other."""
+def scope_names(op_name: str) -> set[str]:
+    """The names on an op's name stack: each component of each of its
+    `op_name` paths but the last, which names the primitive, taken out of
+    the transformations around it (`vmap(jvp(moe))` -> `moe`).  These are
+    the program's `jax.named_scope`s and the names JAX puts there (a jitted
+    function's, `while`, `checkpoint`, an einsum's subscripts)."""
+    out = set()
+    for path in op_name.split(";"):
+        for component in path.split("/")[:-1]:
+            m = _NAME.match(component)
+            if m and m.group(1):
+                out.add(m.group(1))
+    return out
+
+
+def op_names(hlo_text: str) -> dict[str, str]:
+    """{instruction name: op_name} of every instruction in an HLO module's
+    text that carries an `op_name`."""
     out = {}
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
         if m:
-            out[m.group(1)] = part_of(m.group(2))
+            out[m.group(1)] = m.group(2)
     return out
+
+
+def op_parts(hlo_text: str) -> dict[str, str]:
+    """{instruction name: part} of every instruction in an HLO module's
+    text that carries an `op_name`; an instruction without one is other."""
+    return {op: part_of(n) for op, n in op_names(hlo_text).items()}
 
 
 def sync_collectives(hlo_text: str) -> frozenset[str]:
@@ -127,15 +155,23 @@ def round_ops(events: dict) -> tuple[dict[str, float], int]:
     return out, len(runs)
 
 
-def split(events: dict, parts: dict[str, str]) -> dict[str, float]:
-    """{part: device self time per run of the round program}, each of
-    PARTS, for the ops of the runs in the window; an op `parts` does not
-    name is other."""
+def summarize(events: dict, names: dict[str, str]) -> dict:
+    """The round's device self time per run of the round program, over its
+    runs in the window on its first device, with `names` ({instruction:
+    op_name}, `op_names`): "parts", {part: time} of each of PARTS, and
+    "scopes", {name: time of the ops with that name on their stack} of
+    every name on the round's stacks (`scope_names`), each of SCOPES
+    included (0.0 where no op has it).  An op `names` does not name is in
+    other and in no scope."""
     ops, rounds = round_ops(events)
-    out = dict.fromkeys(PARTS, 0.0)
+    parts = dict.fromkeys(PARTS, 0.0)
+    inclusive = dict.fromkeys(SCOPES, 0.0)
     for op, t in ops.items():
-        out[parts.get(op, "other")] += t / rounds
-    return out
+        name = names.get(op, "")
+        parts[part_of(name)] += t / rounds
+        for s in scope_names(name):
+            inclusive[s] = inclusive.get(s, 0.0) + t / rounds
+    return {"parts": parts, "scopes": inclusive}
 
 
 def engine_idle(events: dict) -> dict[str, float]:

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Record a chip trace split by the round's parts, for the tests in
+"""Record a chip trace split by the round's scopes, for the tests in
 bench/tests/data.
 
     python3 bench/tests/record_scopes.py --workload <cell> --seed <n> \
@@ -9,11 +9,12 @@ bench/tests/data.
 Makes one `--trace 1` run of the cell on the chip and prints its result
 line as bench/run.py does.  Writes the compact event list of the traced
 window with the engine's host spans (`scopes.engine_spans`), cut to its
-first `--rounds` round programs (`trace.cut`), beside the part of each op
-in the cut (`scopes.op_parts` of the compiled round the window ran).  Then
-prints one JSON line: the whole window's split per round, the device-idle
-time under each engine span per round (both in ms), the longest ops of
-other, and the engine's compile stats at the end of set-up.  `--hlo`
+first `--rounds` round programs (`trace.cut`), beside the `op_name` of
+each op in the cut (`scopes.op_names` of the compiled round the window
+ran) and the harness's split of the whole window (its record's
+`trace["parts"]` and `trace["scopes"]`).  Then prints one JSON line: that
+split, the device-idle time under each engine span per round (both in
+ms), the longest ops of other, and the engine's compile seconds.  `--hlo`
 also keeps the compiled round's text, to look up by hand what an op of
 other is.
 """
@@ -48,7 +49,7 @@ def main(argv=None) -> int:
 
     kept = {}
     compact, compiled_round = trace.compact, RoundEngine.compiled_round
-    first_rounds = harness.first_rounds
+    read_metrics = harness.read_metrics
 
     def keep_events(profile_dir):
         events = compact(profile_dir)
@@ -60,14 +61,13 @@ def main(argv=None) -> int:
         kept["text"] = compiled.as_text()
         return compiled
 
-    def keep_stats(sys_, *a, **k):
-        out = first_rounds(sys_, *a, **k)
-        kept["compile_stats"] = sys_.eng.compile_stats()
-        return out
+    def keep_record(cell, record, traced):
+        kept["record"] = record
+        return read_metrics(cell, record, traced)
 
     trace.compact = keep_events
     RoundEngine.compiled_round = keep_text
-    harness.first_rounds = keep_stats
+    harness.read_metrics = keep_record
     harness.check_chips(1)
     harness.enable_compile_cache()
     result = harness.run_cell(ROOT, args.workload, args.seed, args.seconds,
@@ -77,34 +77,34 @@ def main(argv=None) -> int:
     if args.hlo:
         with gzip.open(args.hlo, "wt") as f:
             f.write(kept["text"])
-    events, parts = kept["events"], scopes.op_parts(kept["text"])
+    events, names = kept["events"], scopes.op_names(kept["text"])
+    summ, counters = kept["record"]["trace"], kept["record"]["counters"]
+    split = {k: summ[k] for k in ("rounds", "round_busy_s", "parts",
+                                  "scopes")}
     cut = trace.cut(events, args.rounds)
     ops = {scopes.instruction(cut["names"][e[0]])
            for dev in cut["devices"].values() for e in dev["ops"]}
     with open(args.out, "w") as f:
         json.dump({"cell": args.workload, "seed": args.seed,
-                   "parts": {op: parts.get(op, "other")
-                             for op in sorted(ops)},
-                   "events": cut}, f)
+                   "op_names": {op: names[op] for op in sorted(ops)
+                                if op in names},
+                   "trace": split, "events": cut}, f)
 
-    summ = trace.summarize(events)
     by_op, rounds = scopes.round_ops(events)
     full = {scopes.instruction(n): n[:trace.NAME_CHARS]
             for n in events["names"]}
     other = sorted(((t / rounds * 1e3, full[op]) for op, t in by_op.items()
-                    if parts.get(op, "other") == "other"), reverse=True)
-    stats = kept["compile_stats"]
+                    if scopes.part_of(names.get(op, "")) == "other"),
+                   reverse=True)
     print(json.dumps({
         "cell": args.workload, "rounds": rounds,
         "round_device_ms": summ["round_busy_s"] * 1e3,
         "host_gap_ms": summ["round_gap_idle_s"] * 1e3,
-        "split_ms": {k: v * 1e3 for k, v in
-                     scopes.split(events, parts).items()},
+        "split_ms": {k: v * 1e3 for k, v in summ["parts"].items()},
         "engine_idle_ms": {k: v * 1e3 for k, v in
                            scopes.engine_idle(events).items()},
         "other_ops_ms": other[:TOP],
-        "setup_compile_s": stats["compile_s"],
-        "compiled_at": stats["compiled_at"]}), flush=True)
+        "compile_s": counters["compile_s"]}), flush=True)
     return 0
 
 

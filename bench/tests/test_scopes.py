@@ -1,14 +1,17 @@
-"""The round's parts and the engine's spans: the scopes the program puts
-in its compiled round, the reduction of a trace by part and by engine span
-(bench/scopes.py) on a hand-made event list and on events recorded from a
-chip run (bench/tests/data), the engine's spans in a CPU profile, and its
-compile counter."""
+"""The round's parts, its scopes and the engine's spans: the scopes the
+program puts in its compiled round, the reduction of a trace by part, by
+scope and by engine span (bench/scopes.py) on a hand-made event list and on
+events recorded from a chip run (bench/tests/data), the readers of the
+parts, a scope and reader added as new files only, the engine's spans in a
+CPU profile, and its compile counter."""
 import contextlib
 import json
 import os
+import sys
 import tempfile
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -52,6 +55,19 @@ def _round_text(sys_, state, t):
 ])
 def test_part_of_an_op_path(op_name, part):
     assert scopes.part_of(op_name) == part
+
+
+@pytest.mark.parametrize("op_name, names", [
+    ("jit(step)/grad/vmap(jvp(moe))/tanh", {"step", "grad", "moe"}),
+    ("jit(round_fn)/while/body/vmap(grad)/transpose(jvp())/checkpoint/"
+     "rematted_computation/dot_general",
+     {"round_fn", "while", "body", "grad", "checkpoint",
+      "rematted_computation"}),
+    ("jit(f)/sync/add;jit(f)/telemetry/sqrt", {"f", "sync", "telemetry"}),
+    ("reduce_sum", set()),
+])
+def test_scope_names_of_an_op_path(op_name, names):
+    assert scopes.scope_names(op_name) == names
 
 
 def test_op_parts_of_hlo_text():
@@ -188,15 +204,20 @@ def _hand_made():
 
 def test_split_and_engine_idle_of_hand_made_events():
     events = _hand_made()
-    parts = {"fusion.1": "backward", "fusion.2": "optimizer",
-             "copy.4": "sync"}
-    got = scopes.split(events, parts)
+    names = {"fusion.1": "jit(r)/grad/transpose(jvp(attn))/mul",
+             "fusion.2": "jit(r)/optimizer/add", "copy.4": "jit(r)/sync/copy"}
+    summ = scopes.summarize(events, names)
+    got = summ["parts"]
     # self times: the while net of the fusion inside it, 100 then 150
     want = {"forward": 0, "backward": (100 + 50) / 2, "optimizer": 80 / 2,
             "sync": 100 / 2, "telemetry": 0, "other": (100 + 150) / 2}
     assert got == pytest.approx({k: v * 1e-9 for k, v in want.items()})
     s = trace.summarize(events)
     assert sum(got.values()) == pytest.approx(s["round_busy_s"])
+    # inclusive: every name on an op's stack; a part scope with no op is 0
+    assert summ["scopes"] == pytest.approx({
+        "grad": 75e-9, "attn": 75e-9, "optimizer": 40e-9, "sync": 50e-9,
+        "telemetry": 0.0, "r": 165e-9})
     idle = scopes.engine_idle(events)
     # args: [40, 90) all idle; [440, 560), the batch fetch inside it, less
     # the stack op's 50.  launch: [90, 100) and [560, 590), all idle
@@ -212,18 +233,132 @@ def _recorded(name):
 
 def test_recorded_one_chip_split():
     """Three rounds of a `--trace 1` run of starcoder2-3b-L1.h2.1chip on a
-    TPU v5e with the engine's spans, cut by `trace.cut`, beside the part
-    of each op in the cut from the compiled round."""
+    TPU v5e with the engine's spans, cut by `trace.cut`, beside the
+    `op_name` of each op in the cut from the compiled round and the
+    harness's split of the whole window (bench/tests/record_scopes.py)."""
     rec = _recorded("starcoder2-3b-L1.h2.1chip")
-    events, parts = rec["events"], rec["parts"]
+    events, want = rec["events"], rec["trace"]
     s = trace.summarize(events)
     assert s["rounds"] == 3
-    got = scopes.split(events, parts)
-    assert sum(got.values()) == pytest.approx(s["round_busy_s"], rel=5e-3)
-    assert all(got[p] > 0 for p in NAMED), got
-    # AdamW over the flat [2, 247 M] bucket is one fusion of the optimizer
-    assert parts["fusion.72"] == "optimizer"
-    assert got["optimizer"] == pytest.approx(0.043, rel=0.1)
+    got = scopes.summarize(events, rec["op_names"])
+    parts = got["parts"]
+    assert sum(parts.values()) == pytest.approx(s["round_busy_s"], rel=1e-4)
+    assert all(parts[p] > 0 for p in NAMED), parts
+    # the rounds of the cut split as the whole window did
+    assert parts == pytest.approx(want["parts"], rel=5e-3)
+    assert set(got["scopes"]) == set(want["scopes"])
+    for name, t in want["scopes"].items():
+        assert got["scopes"][name] == pytest.approx(t, rel=5e-3, abs=1e-6)
+    # the part scopes hold no other, so `grad` is forward and backward
+    assert got["scopes"]["grad"] == pytest.approx(
+        parts["forward"] + parts["backward"], rel=1e-9)
+    assert got["scopes"]["telemetry"] >= parts["telemetry"]
+    # AdamW over the flat [2, 247 M] bucket is one fusion of the optimizer,
+    # about 43 ms a round
+    assert scopes.part_of(rec["op_names"]["fusion.60"]) == "optimizer"
+    assert parts["optimizer"] == pytest.approx(0.043, rel=0.1)
     idle = scopes.engine_idle(events)
     assert set(idle) == {"repro.engine.args", "repro.engine.launch"}
     assert sum(idle.values()) <= s["round_gap_idle_s"]
+
+
+def _toy_round(scope):
+    """The compiled text of a small jitted step: a vmapped gradient in
+    `grad`, whose loss puts its matmul under `scope("moe")`, then an update
+    in `optimizer`."""
+    def loss(w, x):
+        with scope("moe"):
+            h = jnp.tanh(x @ w)
+        return jnp.sum(h * h)
+
+    def step(w, x):
+        with jax.named_scope("grad"):
+            g = jax.vmap(jax.grad(loss), in_axes=(None, 0))(w, x)
+        with jax.named_scope("optimizer"):
+            return w - 0.1 * jnp.mean(g, 0)
+
+    w, x = jnp.ones((8, 8)), jnp.ones((4, 3, 8))
+    return jax.jit(step).lower(w, x).compile().as_text()
+
+
+def _one_round_of(ops, dur=10):
+    """Events of a window holding one run of the round program, in which
+    each of `ops` runs for `dur` ns after the other."""
+    names = ["bench.window", "jit_step", *ops]
+    return {"names": names, "host": [[0, 0, 10 + dur * len(ops) + 10]],
+            "devices": {"0": {
+                "ops": [[i + 2, 10 + dur * i, dur] for i in range(len(ops))],
+                "modules": [[1, 10, dur * len(ops)]]}}}
+
+
+def test_a_scope_inside_grad_is_read_inclusively_and_leaves_the_parts():
+    named = scopes.op_names(_toy_round(jax.named_scope))
+    bare = scopes.op_names(_toy_round(lambda name: contextlib.nullcontext()))
+    assert named.keys() == bare.keys()
+    events = _one_round_of(sorted(named))
+    with_moe, without = (scopes.summarize(events, named),
+                         scopes.summarize(events, bare))
+    assert with_moe["parts"] == without["parts"]
+    assert with_moe["parts"]["forward"] > 0
+    assert with_moe["parts"]["backward"] > 0
+    in_moe = [op for op, n in named.items() if "moe" in n]
+    assert in_moe and "moe" not in without["scopes"]
+    assert with_moe["scopes"]["moe"] == pytest.approx(len(in_moe) * 1e-8)
+    # inclusive: every op of `moe` is an op of `grad` too
+    assert with_moe["scopes"]["grad"] == pytest.approx(
+        with_moe["parts"]["forward"] + with_moe["parts"]["backward"])
+    assert with_moe["scopes"]["moe"] < with_moe["scopes"]["grad"]
+    assert with_moe["scopes"]["sync"] == with_moe["scopes"]["telemetry"] == 0
+
+
+def test_a_new_scope_and_reader_are_files_only(tiny_root, tmp_path,
+                                               monkeypatch):
+    """A scope new to the program and a reader file new to metrics/, named
+    in BENCHMARK.json, give a reported metric: nothing of the benchmark's
+    code is edited."""
+    import bench.metrics
+    path = os.path.join(tiny_root, "BENCHMARK.json")
+    with open(path) as f:
+        man = json.load(f)
+    man["per_layer"].append(
+        {"name": "moe_ms", "unit": "ms", "better": "lower",
+         "source": "device_trace", "layer": "local step",
+         "moves": "tokens_per_s"})
+    with open(path, "w") as f:
+        json.dump(man, f)
+    readers = tmp_path / "readers"
+    readers.mkdir()
+    (readers / "moe_ms.py").write_text(
+        "def read(rec):\n"
+        "    t = rec['trace']\n"
+        "    if t is None or 'moe' not in t['scopes']:\n"
+        "        return None\n"
+        "    return t['scopes']['moe'] * 1e3\n")
+    monkeypatch.setattr(bench.metrics, "__path__",
+                        [*bench.metrics.__path__, str(readers)])
+
+    named = scopes.op_names(_toy_round(jax.named_scope))
+    events = _one_round_of(sorted(named))
+    record = {"compiles_in_window": 0,
+              "trace": {**trace.summarize(events),
+                        **scopes.summarize(events, named)}}
+    cell = manifest.load_cell(tiny_root, "tiny-decoder.h2")
+    try:
+        got = harness.read_metrics(cell, record, True)
+        n_moe = sum("moe" in n for n in named.values())
+        assert got["moe_ms"] == {"value": pytest.approx(n_moe * 1e-5),
+                                 "unit": "ms"}
+        assert "moe_ms" not in harness.read_metrics(
+            cell, {**record, "trace": None}, True)
+    finally:
+        sys.modules.pop("bench.metrics.moe_ms", None)
+
+
+@pytest.mark.parametrize("part", ["forward", "backward", "optimizer",
+                                  "telemetry", "other"])
+def test_part_readers(part):
+    read = manifest.metric_reader(part + "_ms")
+    assert read({"trace": None}) is None
+    parts = dict.fromkeys(scopes.PARTS, 0.0)
+    parts[part] = 0.0125
+    assert read({"trace": {"parts": parts}}) == pytest.approx(12.5)
